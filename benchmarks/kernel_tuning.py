@@ -81,14 +81,14 @@ def main():
             "kernel/v2_tuned", C, t_t, chunk=tuned,
             derived=f"x{t_lax / t_t:.2f}_vs_lax"))
 
-    # legacy v1 kernel in isolation (tolerance-equivalent; actual Pallas
-    # interpreter off-TPU, hence the smaller size)
+    # legacy v1 kernel in isolation (tolerance-equivalent; interpret mode
+    # only, so it is compared only where the kernels are interpreted)
     from repro.kernels.seg_scan.ops import segmented_cumsum, segmented_cumsum_v2
 
     rng = np.random.default_rng(1)
     term = jnp.asarray(rng.uniform(0, 5, v1_size).astype(np.float32))
     start = jnp.asarray(rng.uniform(size=v1_size) < 0.1)
-    for chunk in chunks:
+    for chunk in (chunks if path == "interpret" else ()):
         t_v1, _ = timed(segmented_cumsum, term, start.astype(jnp.float32),
                         chunk=chunk, repeats=2)
         entries.append(_scan_entry("kernel/v1", v1_size, t_v1, chunk=chunk))

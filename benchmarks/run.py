@@ -107,6 +107,8 @@ def _check_payload(mod, payload, path):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (batch_grid, checkpoint_resume, core_scaling,
                             dist_scaling, fault_recovery, fig_5_1_scaling,
                             fig_5_4_matchmaking, fig_5_9_mapreduce,

@@ -1,6 +1,6 @@
 """Cloud²Sim core: the paper's contribution as composable JAX modules.
 
-  compat       version-tolerant jax shims (shard_map location / kwarg renames)
+  compat       the Pallas kernel mode: compiled on TPU, interpreted on CPU
   partition    PartitionUtil + 271-virtual-shard consistent partition table
   grid         DataGrid — the in-memory data grid over a device mesh
   executor     DistributedExecutor — logic-to-data shard_map execution
@@ -17,4 +17,3 @@
   des_scan     closed-form O(C log C) segmented-scan DES core (+ distributed
                phase-4 and batched scenario sweeps)
 """
-from repro.core.compat import shard_map  # noqa: F401  (re-export the shim)

@@ -1,81 +1,23 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""The Pallas kernel mode: the ONE definition of whether a kernel runs
+compiled or interpreted.
 
-The repo targets the jax 0.4.x series shipped in the image but is written
-against the newer spellings; this module papers over both directions:
-
-  * ``shard_map``      — top-level ``jax.shard_map`` only exists from
-                         jax >= 0.6; before that it lives in
-                         ``jax.experimental.shard_map``.  The replication
-                         check kwarg was also renamed
-                         (``check_rep`` -> ``check_vma``); the wrapper
-                         accepts either and translates.
-  * ``make_mesh``      — the ``axis_types`` kwarg (and
-                         ``jax.sharding.AxisType``) only exist on newer jax;
-                         the wrapper drops the kwarg where unsupported
-                         (``Auto`` is the default there anyway).
-  * ``CompilerParams`` — pallas-TPU renamed ``TPUCompilerParams`` to
-                         ``CompilerParams``; this resolves whichever the
-                         installed jax ships.
-
-It also owns the ONE definition of the Pallas interpret-mode default
-(``resolve_kernel_interpret``) that des_scan and the kernel wrappers used to
-each spell out as ``jax.default_backend() != "tpu"``.
+Kernels run compiled on a TPU backend.  On the CPU backend, where the tests
+run, they run under the Pallas interpreter (or, for the seg-scan kernels, as
+a bit-exact jnp emulation) and a one-time warning says so.  Any other
+backend is refused: a Mosaic kernel cannot compile there, and interpreting
+it would hide that.  ``kernel_path`` records which path a run took.
 """
 from __future__ import annotations
 
-import inspect
 import warnings
 
 import jax
 
-try:                                     # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *args, **kwargs):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename hidden."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, *args, **kwargs)
-
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
-
-# jax.sharding.AxisType.Auto where it exists, else None (the kwarg is dropped).
-AXIS_TYPE_AUTO = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-
-
-def make_mesh(axis_shapes, axis_names, **kwargs):
-    """``jax.make_mesh`` tolerating the ``axis_types`` kwarg's absence."""
-    if "axis_types" not in _MAKE_MESH_PARAMS:
-        kwargs.pop("axis_types", None)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-from jax.experimental.pallas import tpu as _pltpu  # noqa: E402
-
-CompilerParams = (getattr(_pltpu, "CompilerParams", None)
-                  or _pltpu.TPUCompilerParams)
-
-
-# --------------------------------------------- Pallas interpret-mode default
 
 class KernelInterpretFallbackWarning(UserWarning):
-    """``use_kernel=True`` off-TPU runs the kernel's interpret/emulation
-    fallback, not a compiled accelerator kernel — kernel timings measured in
-    this mode are NOT hardware kernel performance."""
-
-
-def pallas_interpret_default() -> bool:
-    """The repo-wide Pallas interpret default: compiled on TPU, interpret
-    (or bit-exact jnp emulation, for kernels that provide one) elsewhere."""
-    return jax.default_backend() != "tpu"
+    """``use_kernel=True`` on the CPU backend runs the kernel's interpret/
+    emulation path, not a compiled accelerator kernel — kernel timings
+    measured in this mode are NOT hardware kernel performance."""
 
 
 _warned_interpret_fallback = False
@@ -83,27 +25,31 @@ _warned_interpret_fallback = False
 
 def resolve_kernel_interpret(interpret, *, warn: bool = True,
                              context: str = "seg_scan") -> bool:
-    """Resolve an ``interpret=None`` kernel flag to the backend default.
-
-    The previously thrice-duplicated ``jax.default_backend() != "tpu"``
-    default lives HERE.  When the default silently lands on the fallback
-    (``use_kernel=True`` on a non-TPU backend), a one-time
-    ``KernelInterpretFallbackWarning`` is emitted so CPU "kernel" runs can't
-    masquerade as compiled-kernel measurements; an EXPLICIT
-    ``interpret=True`` is a deliberate choice and never warns."""
+    """Resolve an ``interpret=None`` kernel flag to the backend's mode:
+    compiled (False) on TPU, interpreted (True) on CPU, an error anywhere
+    else.  The CPU default emits a one-time
+    ``KernelInterpretFallbackWarning`` so CPU "kernel" runs can't masquerade
+    as compiled-kernel measurements; an EXPLICIT ``interpret`` is a
+    deliberate choice and never warns."""
     global _warned_interpret_fallback
     if interpret is not None:
         return bool(interpret)
-    interpret = pallas_interpret_default()
-    if interpret and warn and not _warned_interpret_fallback:
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend != "cpu":
+        raise RuntimeError(
+            f"the {context} kernel has no path on backend {backend!r}: it "
+            f"compiles for a TPU and is interpreted on the CPU; pass "
+            f"interpret=True to interpret it here")
+    if warn and not _warned_interpret_fallback:
         _warned_interpret_fallback = True
         warnings.warn(
-            f"use_kernel=True on backend {jax.default_backend()!r}: the "
-            f"{context} kernel falls back to interpret/emulation mode "
-            f"(kernel_path='interpret'); timings do not reflect compiled "
-            f"accelerator kernels", KernelInterpretFallbackWarning,
-            stacklevel=3)
-    return interpret
+            f"use_kernel=True on backend 'cpu': the {context} kernel runs "
+            f"in interpret/emulation mode (kernel_path='interpret'); timings "
+            f"do not reflect compiled accelerator kernels",
+            KernelInterpretFallbackWarning, stacklevel=3)
+    return True
 
 
 def kernel_path(use_kernel: bool, interpret=None):

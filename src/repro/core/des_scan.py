@@ -21,14 +21,12 @@ partitionable by VM ownership (distributed phase 4).
 Execution paths:
   * ``simulate_completion_scan``        — pure-jnp sort + segmented cumsum
   * ``use_kernel=True``                 — the v2 position-gated fused kernel
-                                          (``kernels/seg_scan/v2``): one
-                                          3-operand stable sort replaces
-                                          lexsort + two gathers, the chunked
-                                          Pallas scan reproduces the lax
+                                          (``kernels/seg_scan/v2``): the
+                                          chunked Pallas scan reproduces the lax
                                           addition tree BIT-exactly, and the
                                           sentinel mask + result scatter are
                                           fused into the epilogue kernel.
-                                          Off-TPU the kernel falls back to a
+                                          On the CPU the kernel runs as a
                                           bit-exact jnp emulation (one-time
                                           ``KernelInterpretFallbackWarning``);
                                           ``kernel_chunk=None`` resolves via
@@ -37,7 +35,7 @@ Execution paths:
   * ``simulate_completion_distributed`` — COMPUTE-partitioned phase 4: an
                                           owner-keyed exchange re-homes each
                                           cloudlet to the member owning its
-                                          VM, and each member lexsorts+scans
+                                          VM, and each member sorts+scans
                                           only its own ~C/M cloudlets
   * ``run_simulation_batch``            — one jit over a multi-axis scenario
                                           GRID (seeds × mi_scale × broker ×
@@ -62,7 +60,7 @@ The exchange protocol (``method="exchange"``, the default distributed core):
      segment — padding contributes exactly 0.0.  Capacity violations are
      counted on-device and raised as ``ExchangeCapacityError`` — loud, never
      silent truncation.
-  3. The owner lexsorts + scans only its own cloudlets: per-member work drops
+  3. The owner sorts + scans only its own cloudlets: per-member work drops
      from O(C log C), replicated M times, to O((C/M) log(C/M)) each.
   4. Finish partials are scattered back to global row positions and psum-med;
      partials are disjoint (each cloudlet has exactly one owner) and
@@ -97,6 +95,44 @@ from repro.core.dispatch import CompileCache, DispatchJob
 _EPS = 1e-6   # same "still running" threshold as the wave-loop reference
 
 
+def _segment_start_index(start, block: int = 1024):
+    """Index of each element's segment start: the running max of
+    ``where(start, idx, 0)``.  Integer max is exact, so this is bitwise
+    ``lax.cummax`` of that array; it runs blocked, as a cummax along
+    ``block``-wide rows plus one over the row maxima, because the TPU
+    compiler takes ~40 s for a single 1-D cummax of 2**20 elements and
+    well under a second for the blocked form."""
+    C = start.shape[0]
+    idx = jnp.arange(C, dtype=jnp.int32)
+    marks = jnp.where(start, idx, 0)
+    if C <= block:
+        return jax.lax.cummax(marks)
+    pad = (-C) % block
+    rows = jnp.concatenate([marks, jnp.zeros((pad,), jnp.int32)]).reshape(
+        -1, block)
+    within = jax.lax.cummax(rows, axis=1)
+    before = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jax.lax.cummax(within[:-1, -1])])
+    return jnp.maximum(within, before[:, None]).reshape(-1)[:C]
+
+
+def _sort_by_segment_and_length(seg, mi):
+    """The stable (seg, mi) order of the rows: ``(seg_s, mi_s, order)``.
+
+    Two stable one-key sorts, the minor key first, each carrying only a row
+    index.  Runnable lengths are positive f32s, whose bit patterns order
+    like the floats, so the length is compared as an int32.  The TPU
+    compiler builds this pair in about the time of one such sort (~30 s at
+    2**20 rows), where a single sort on (seg, mi, row) keys takes it
+    minutes."""
+    idx = jnp.arange(seg.shape[0], dtype=jnp.int32)
+    mi_bits = jax.lax.bitcast_convert_type(mi, jnp.int32)
+    _, by_mi = jax.lax.sort((mi_bits, idx), num_keys=1, is_stable=True)
+    seg_s, q = jax.lax.sort((seg[by_mi], idx), num_keys=1, is_stable=True)
+    order = by_mi[q]
+    return seg_s, mi[order], order
+
+
 def _segmented_cumsum(term, start):
     """Segmented inclusive prefix sum, position-gated Hillis–Steele.
 
@@ -114,8 +150,7 @@ def _segmented_cumsum(term, start):
     segmented-operator scan this replaces."""
     C = term.shape[0]
     idx = jnp.arange(C, dtype=jnp.int32)
-    seg_start = jax.lax.cummax(jnp.where(start, idx, 0))   # exact int scan
-    pos = idx - seg_start                                  # in-segment p
+    pos = idx - _segment_start_index(start)                # in-segment p
     x = term
     d = 1
     while d < C:
@@ -138,12 +173,10 @@ def simulate_completion_scan(vm_assign, cloudlet_mi, vm_mips, valid, *,
     invalid padding rows, zero-length cloudlets, cloudlets bound to
     zero-MIPS (padded) VMs — keep finish time 0, exactly like the wave loop.
 
-    ``use_kernel=True`` runs the v2 fused kernel path, BIT-identical to the
-    default path: one stable 3-operand ``lax.sort`` carries (seg, mi, row)
-    together (same permutation as the lexsort, without the two post-sort
-    gathers), ``seg_cumsum_v2`` reproduces ``_segmented_cumsum``'s exact
-    position-gated addition tree, and the sentinel mask + scatter fuse into
-    the epilogue.  ``kernel_chunk`` (power of two, static) picks the
+    ``use_kernel=True`` runs the v2 kernel path, BIT-identical to the
+    default path: after the same sort, ``seg_cumsum_v2`` reproduces
+    ``_segmented_cumsum``'s exact position-gated addition tree, and the
+    sentinel mask + scatter fuse into the epilogue.  ``kernel_chunk`` (power of two, static) picks the
     in-kernel level split; ``None`` asks the roofline autotuner for the
     persisted/analytic choice.  ``interpret=None`` resolves to the backend
     default — compiled on TPU, bit-exact jnp emulation elsewhere (a
@@ -157,24 +190,12 @@ def simulate_completion_scan(vm_assign, cloudlet_mi, vm_mips, valid, *,
     runnable = valid & (mi > _EPS) & (mips[vm_assign] > 0.0)
     seg = jnp.where(runnable, vm_assign, V).astype(jnp.int32)
 
+    seg_s, mi_s, order = _sort_by_segment_and_length(seg, mi)
     idx = jnp.arange(C, dtype=jnp.int32)
-    if use_kernel:
-        # fused gather: ONE stable sort with (seg, mi) keys carries mi and
-        # the row index as payload — the identical permutation to
-        # lexsort((mi, seg)) (both are the stable (seg, mi) sort), minus
-        # the two O(C) gathers the lax path pays after it.
-        seg_s, mi_s, order = jax.lax.sort((seg, mi, idx), num_keys=2,
-                                          is_stable=True)
-    else:
-        # lexicographic sort: primary by segment, secondary by length asc
-        order = jnp.lexsort((mi, seg))
-        seg_s = seg[order]
-        mi_s = mi[order]
 
     prev_seg = jnp.concatenate([jnp.full((1,), -1, jnp.int32), seg_s[:-1]])
     start = seg_s != prev_seg                       # segment boundaries
-    seg_start = jax.lax.cummax(jnp.where(start, idx, 0))
-    pos = (idx - seg_start).astype(jnp.float32)     # j-1 within the segment
+    pos = (idx - _segment_start_index(start)).astype(jnp.float32)  # j-1
 
     # sharers count k per segment, gathered back per element
     counts = jax.ops.segment_sum(jnp.ones((C,), jnp.float32), seg_s,
@@ -198,7 +219,7 @@ def simulate_completion_scan(vm_assign, cloudlet_mi, vm_mips, valid, *,
         f_s = seg_cumsum_v2(term, start, chunk=kernel_chunk,
                             interpret=interpret)
         sentinel = seg_s == V                       # sentinel never finishes
-        finish = scatter_finish_v2(f_s, order, sentinel, chunk=kernel_chunk,
+        finish = scatter_finish_v2(f_s, order, sentinel,
                                    interpret=interpret)
         f_s = jnp.where(sentinel, 0.0, f_s)
     else:
@@ -323,7 +344,7 @@ def _dist_core_replicated(mesh, axis, V, use_kernel, interpret,
 def _dist_core_exchange(mesh, axis, V, C_pad, block, use_kernel, interpret,
                         kernel_chunk=None):
     """Compute-partitioned distributed core: bucket by VM owner, all-to-all,
-    then each member lexsorts + scans ONLY its own cloudlets.  ``C_pad`` and
+    then each member sorts + scans ONLY its own cloudlets.  ``C_pad`` and
     ``block`` (the per-(src, dst) exchange capacity) are static — part of
     this cache key — while the VM→member ownership map stays a RUNTIME
     operand, so rebalancing the partition table never recompiles."""
@@ -350,8 +371,7 @@ def _dist_core_exchange(mesh, axis, V, C_pad, block, use_kernel, interpret,
         dest_s = dest[order]
         idx = jnp.arange(S, dtype=jnp.int32)
         prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), dest_s[:-1]])
-        bucket_start = jax.lax.cummax(jnp.where(dest_s != prev, idx, 0))
-        rank = idx - bucket_start                 # position within bucket
+        rank = idx - _segment_start_index(dest_s != prev)  # within bucket
         live = dest_s < M                         # invalid rows don't ship
         # overflowed rows land OUT of range and are dropped — but counted,
         # so the caller can fail loudly instead of returning wrong results

@@ -488,7 +488,11 @@ class ElasticDispatcher:
 
         self.devices = list(devices if devices is not None else jax.devices())
         self.axis = axis
-        n0 = max(1, min(start_members, len(self.devices)))
+        if not 1 <= start_members <= len(self.devices):
+            raise ValueError(
+                f"start_members={start_members} needs 1..{len(self.devices)}"
+                f" members: only {len(self.devices)} device(s) in the pool")
+        n0 = int(start_members)
         self.table = PartitionTable(
             partition_count=partition_count or DEFAULT_PARTITION_COUNT,
             n_instances=n0)
@@ -1778,18 +1782,12 @@ class ElasticDispatcher:
             self.cache.put(key, fn)
         return fn
 
-    @property
-    def _chunk_donate(self):
-        """donate_argnums for the chunk buffer (argnum 0, the chunk tree):
-        it is used exactly once, so XLA can recycle its memory for outputs —
-        steady-state streaming then allocates nothing.  The valid mask is
-        NOT donated: it is memoized across chunks (``_stage_host``) and
-        donation would delete it under the later chunks.  Decided per
-        dispatcher from its OWN devices (never ``jax.default_backend``,
-        which would pin the process backend at import and misjudge
-        mixed-backend use); CPU has no donation support and would only warn
-        per compile."""
-        return () if self.devices[0].platform == "cpu" else (0,)
+    # donate_argnums for the chunk buffer (argnum 0, the chunk tree): it is
+    # staged afresh for every launch, replays included, and used exactly
+    # once, so XLA can recycle its memory for outputs — steady-state
+    # streaming then allocates nothing.  The valid mask is NOT donated: it
+    # is memoized across chunks (``_stage_host``).
+    _CHUNK_DONATE = (0,)
 
     def _build_member(self, job: DispatchJob):
         executor = self.executor          # bound to the key's mesh
@@ -1822,7 +1820,7 @@ class ElasticDispatcher:
             return out
 
         if not job.deterministic:
-            return jax.jit(call, donate_argnums=self._chunk_donate)
+            return jax.jit(call, donate_argnums=self._CHUNK_DONATE)
 
         # deterministic: the row tree compiles as its OWN executable so the
         # member_fn's producer can never FMA-contract into the level-0 adds
@@ -1834,7 +1832,7 @@ class ElasticDispatcher:
                 body, (chunk_tree, valid), replicated_args=rep,
                 out_specs=out_specs)
 
-        rows_fn = jax.jit(rows_call, donate_argnums=self._chunk_donate)
+        rows_fn = jax.jit(rows_call, donate_argnums=self._CHUNK_DONATE)
         tree_fn = jax.jit(lambda out, valid: jax.tree_util.tree_map(
             lambda a: _row_tree_sum(a, valid), out))
 
@@ -1850,7 +1848,7 @@ class ElasticDispatcher:
         def run(chunk_tree, valid, *rep):
             return job.global_fn(chunk_tree, valid, *rep)
 
-        jitted = jax.jit(run, donate_argnums=self._chunk_donate)
+        jitted = jax.jit(run, donate_argnums=self._CHUNK_DONATE)
         # deterministic: the row tree compiles as its OWN executable (a
         # nested jit would inline into the outer trace) so the global_fn's
         # producer can never FMA-contract into the level-0 adds — the same

@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map
-
 
 @functools.partial(jax.jit, static_argnames=("length",))
 def _slice_chunk(src, lo, n_live, *, length):
@@ -91,7 +89,7 @@ class DistributedExecutor:
         out_specs = out_specs if out_specs is not None else P(self.axis)
         rep = P()
 
-        f = shard_map(
+        f = jax.shard_map(
             lambda d, *r: fn(d, *r), mesh=self.mesh,
             in_specs=(jax.tree_util.tree_map(lambda _: in_spec, data),
                       *[jax.tree_util.tree_map(lambda _: rep, a)
@@ -114,7 +112,7 @@ class DistributedExecutor:
                 return jax.lax.all_gather(mapped, axis, tiled=True)
             raise ValueError(reduce_kind)
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(jax.tree_util.tree_map(lambda _: P(axis), data),
                       *[jax.tree_util.tree_map(lambda _: P(), a)

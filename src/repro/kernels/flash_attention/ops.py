@@ -10,6 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.compat import resolve_kernel_interpret
 from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
                                                   flash_attention_fwd,
                                                   flash_attention_fwd_lse)
@@ -17,7 +18,8 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return resolve_kernel_interpret(None, warn=False,
+                                    context="flash_attention")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import CompilerParams
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_d_blocks: int):
     di = pl.program_id(3)
@@ -59,7 +57,7 @@ def grouped_matmul(x, w, *, block_c: int = 128, block_f: int = 128,
                                lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

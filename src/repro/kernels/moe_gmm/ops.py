@@ -3,18 +3,19 @@ import functools
 
 import jax
 
+from repro.core.compat import resolve_kernel_interpret
 from repro.kernels.moe_gmm.kernel import grouped_matmul
 from repro.kernels.moe_gmm.ref import grouped_matmul_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    return resolve_kernel_interpret(None, warn=False, context="moe_gmm")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _gmm(x, w, block_c, block_f, block_d):
     return grouped_matmul(x, w, block_c=block_c, block_f=block_f,
-                          block_d=block_d, interpret=not _on_tpu())
+                          block_d=block_d, interpret=_interpret())
 
 
 def _gmm_fwd(x, w, block_c, block_f, block_d):
@@ -25,7 +26,7 @@ def _gmm_bwd(block_c, block_f, block_d, res, g):
     # both cotangents are themselves grouped matmuls -> reuse the kernel:
     #   dx (E,C,D) = g (E,C,F) @ w^T (E,F,D);  dw (E,D,F) = x^T (E,D,C) @ g
     x, w = res
-    interp = not _on_tpu()
+    interp = _interpret()
     dx = grouped_matmul(g, w.transpose(0, 2, 1), block_c=block_c,
                         block_f=block_d, block_d=block_f, interpret=interp)
     dw = grouped_matmul(x.transpose(0, 2, 1), g, block_c=block_d,

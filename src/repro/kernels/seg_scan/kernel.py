@@ -5,6 +5,10 @@ is an (L×L) masked matmul (MXU-friendly), across chunks a single running
 value is carried in scratch — the carry only survives into a chunk until its
 first segment boundary.  Grid = (chunks,) sequential, so the carry lives on
 chip for the whole array.
+
+Interpret mode only: its (1, L) blocks and lane-to-scalar carry have no
+Mosaic lowering, and nothing on the main path uses it (the DES core runs
+the bit-identical v2 kernel, ``v2.py``).  ``interpret=False`` is refused.
 """
 from __future__ import annotations
 
@@ -12,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.compat import CompilerParams
 
 
 def _seg_cumsum_kernel(term_ref, reset_ref, out_ref, carry_ref):
@@ -50,6 +52,10 @@ def _seg_cumsum_kernel(term_ref, reset_ref, out_ref, carry_ref):
 def seg_cumsum(term, reset, *, chunk: int = 128, interpret: bool = False):
     """Segmented inclusive prefix sum of ``term`` (1D), restarting wherever
     ``reset`` is nonzero.  term: (C,) f32; reset: (C,) f32 -> (C,) f32."""
+    if not interpret:
+        raise NotImplementedError(
+            "the v1 seg_cumsum kernel runs only in interpret mode; the "
+            "compiled path is seg_cumsum_v2")
     C = term.shape[0]
     chunk = min(chunk, max(C, 1))
     pad = (-C) % chunk
@@ -71,7 +77,7 @@ def seg_cumsum(term, reset, *, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, chunk), lambda c: (c, 0)),
         out_shape=jax.ShapeDtypeStruct((nc, chunk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tr, rr)

@@ -1,9 +1,7 @@
 """Jitted wrappers for the segmented-cumsum kernels (interpret off-TPU).
 
 The interpret default is the ONE in ``core/compat.py``
-(``resolve_kernel_interpret``) — the same helper des_scan's entry points
-use, so all three former copies of ``jax.default_backend() != "tpu"``
-resolve identically.
+(``resolve_kernel_interpret``), the same helper des_scan's entry points use.
 """
 import functools
 

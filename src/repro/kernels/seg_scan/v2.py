@@ -15,12 +15,14 @@ a position-gated Hillis–Steele doubling scan over the whole array:
 where ``pos`` is the element's in-segment position.  v2 splits the SAME
 step set at the chunk length L (a power of two):
 
-  * steps ``d < L`` run inside a Pallas kernel, one grid step per chunk.
-    The operand ``x_j(p - d)`` crosses the chunk edge only into the
-    previous chunk's last ``d`` lanes, so the kernel carries each level's
-    full before-state in a ``(log2 L, L)`` VMEM scratch: at level ``j`` it
-    reads the previous chunk's saved ``x_j``, saves its own, then applies
-    the gated add.  The grid is sequential, so the carry never leaves chip.
+  * steps ``d < L`` run inside a Pallas kernel.  The flat array is laid
+    out as rows of W = max(L, 128) lanes and each grid step takes an
+    (8, W) block: eight consecutive rows, one vreg-aligned tile row.  The
+    operand ``x_j(p - d)`` of a row's first ``d`` lanes lives in the
+    previous row's last ``d`` lanes, so each level rolls the block down one
+    sublane and takes row 0's operand from a ``(log2 L, 8, W)`` VMEM
+    scratch that holds the previous block's last row at every level.  The
+    grid is sequential, so the carry never leaves the chip.
   * steps ``d >= L`` (all multiples of L) run as plain jnp shifts on the
     flat result — a shift by a multiple of L preserves chunk-local offsets,
     so these are ordinary global Hillis–Steele steps.
@@ -46,10 +48,13 @@ Execution modes (``interpret`` resolved by ``compat.resolve_kernel_interpret``):
 
 ``scatter_finish_v2`` is the fused epilogue: sentinel masking + the
 scatter back to pre-sort row order in one kernel (one pass over the
-result instead of a masked select materialized between two XLA ops).
+result instead of a masked select materialized between two XLA ops).  Its
+per-element operands are read as scalars from SMEM; the output is held in
+VMEM as (8, 128) tiles.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -57,7 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import CompilerParams, resolve_kernel_interpret
+from repro.core.compat import resolve_kernel_interpret
 
 
 def _pow2_ceil(n: int) -> int:
@@ -65,12 +70,11 @@ def _pow2_ceil(n: int) -> int:
 
 
 def _in_segment_pos(start):
-    """In-segment position — the VERBATIM op sequence ``_segmented_cumsum``
-    uses (exact int scan), so the gate values are bit-identical."""
-    C = start.shape[0]
-    idx = jnp.arange(C, dtype=jnp.int32)
-    seg_start = jax.lax.cummax(jnp.where(start, idx, 0))
-    return idx - seg_start
+    """In-segment position — the op sequence ``_segmented_cumsum`` uses
+    (exact int scan), so the gate values are bit-identical."""
+    from repro.core.des_scan import _segment_start_index
+    return jnp.arange(start.shape[0], dtype=jnp.int32) - \
+        _segment_start_index(start)
 
 
 def _emulate(term, pos):
@@ -86,51 +90,55 @@ def _emulate(term, pos):
     return x
 
 
-def _scan_kernel(levels, term_ref, pos_ref, out_ref, carry_ref):
-    """In-chunk steps d = 1..L/2 with each level's inter-chunk operand
-    carried in scratch.  ``carry_ref[j]`` holds the PREVIOUS chunk's state
-    before step 2^j; it is read, then overwritten with this chunk's state,
-    then the gated add runs — the save-before-update order is what makes
-    the next grid step see exactly ``x_j`` of this chunk."""
-    ci = pl.program_id(0)
+_ROWS = 8        # sublanes per block: each block is one (8, W) tile row
+_LANES = 128     # lanes per vreg; the block width W is a multiple of it
 
-    @pl.when(ci == 0)
+
+def _scan_kernel(levels, term_ref, pos_ref, out_ref, carry_ref):
+    """In-chunk steps d = 1..L/2 over an (R, W) block of R consecutive
+    W-lane rows of the flat array.  The operand ``x_j(p - d)`` of lane
+    ``i < d`` lives in the previous row's last ``d`` lanes: a sublane roll
+    brings row r-1 under row r, and ``carry_ref[j]`` supplies row 0 with
+    the PREVIOUS block's last row at level j.  The carry is saved before
+    the gated add, so the next grid step sees exactly ``x_j`` of this
+    block.  Every shift is a ``pltpu.roll`` plus a select: no unaligned
+    slice or concatenate, so the body lowers on the chip as it runs in the
+    interpreter."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    x = term_ref[...]                       # (1, L)
-    pos = pos_ref[...]                      # (1, L) int32
-    L = x.shape[1]
+    x = term_ref[...]                       # (R, W)
+    pos = pos_ref[...]                      # (R, W) int32
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     zero = jnp.zeros((), x.dtype)
     for j in range(levels):
         d = 1 << j
-        prev = carry_ref[j:j + 1, :]        # previous chunk's x_j, (1, L)
-        carry_ref[j:j + 1, :] = x
-        shifted = jnp.concatenate([prev[:, L - d:], x[:, :L - d]], axis=1)
+        up = pltpu.roll(x, 1, 0)            # row r <- row r-1 (0 <- R-1)
+        prev = jnp.where(row == 0, carry_ref[j], up)
+        carry_ref[j] = up                   # row 0 holds this block's last row
+        shifted = jnp.where(lane >= d, pltpu.roll(x, d, 1),
+                            pltpu.roll(prev, d, 1))
         x = x + jnp.where(pos >= d, shifted, zero)
     out_ref[...] = x
 
 
-def _pallas_in_chunk(term, pos, L: int, interpret: bool):
-    """Run the in-chunk levels (d < L) over the (nc, L) chunk grid."""
-    C_pad = term.shape[0]
-    nc = C_pad // L
-    levels = max(L - 1, 0).bit_length()     # log2(L): steps 1, 2, .., L/2
-    tr = term.reshape(nc, L)
-    pr = pos.reshape(nc, L)
+def _pallas_in_chunk(term, pos, levels: int, W: int, interpret: bool):
+    """Run the in-chunk levels (d < 2**levels <= W) over (R, W) blocks."""
+    nr = term.shape[0] // W
+    spec = pl.BlockSpec((_ROWS, W), lambda c: (c, 0))
     out = pl.pallas_call(
-        lambda *refs: _scan_kernel(levels, *refs),
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda c: (c, 0)),
-            pl.BlockSpec((1, L), lambda c: (c, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, L), lambda c: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, L), term.dtype),
-        scratch_shapes=[pltpu.VMEM((max(levels, 1), L), term.dtype)],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        functools.partial(_scan_kernel, levels),
+        grid=(nr // _ROWS,),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((nr, W), term.dtype),
+        scratch_shapes=[pltpu.VMEM((max(levels, 1), _ROWS, W), term.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tr, pr)
+    )(term.reshape(nr, W), pos.reshape(nr, W))
     return out.reshape(-1)
 
 
@@ -159,13 +167,16 @@ def seg_cumsum_v2(term, start, *, chunk: int = 128,
         return _emulate(term, pos)
 
     # L = min(chunk, pow2_ceil(C)) keeps the in-kernel step set inside the
-    # lax step set {2^j < C} even when one chunk covers the whole array.
+    # lax step set {2^j < C} even when one chunk covers the whole array;
+    # the block is at least one full 128-lane row wide whatever L is.
     L = min(chunk, _pow2_ceil(C))
-    pad = (-C) % L
+    W = max(L, _LANES)
+    pad = (-C) % (_ROWS * W)
     if pad:        # tail pad: fresh zero segments; sliced off below
         term = jnp.concatenate([term, jnp.zeros((pad,), term.dtype)])
         pos = jnp.concatenate([pos, jnp.zeros((pad,), pos.dtype)])
-    x = _pallas_in_chunk(term, pos, L, interpret=interpret and force_pallas)
+    x = _pallas_in_chunk(term, pos, L.bit_length() - 1, W,
+                         interpret=interpret and force_pallas)
 
     # tail steps d = L, 2L, ... while d < C — plain global shifts; padding
     # sits at the END of the array so element p < C reads exactly the same
@@ -179,29 +190,39 @@ def seg_cumsum_v2(term, start, *, chunk: int = 128,
 
 
 def _scatter_kernel(f_ref, order_ref, sent_ref, out_ref):
-    """Fused epilogue: ``out[order[i]] = sentinel ? 0 : f[i]``, one dynamic
-    store per element.  ``order`` (identity-padded) is a permutation of the
-    padded index range, so every output slot is written exactly once and no
-    init pass over ``out`` is needed beyond the first grid step."""
-    L = f_ref.shape[1]
-    zero = jnp.zeros((1,), out_ref.dtype)
+    """Fused epilogue: ``out[order[i]] = sentinel ? 0 : f[i]``.  The block's
+    operands sit in SMEM, so every per-element read is a scalar load; the
+    whole output stays in VMEM as (8, 128) tiles for the grid's lifetime
+    and each element is a select into its tile, stored back whole.
+    ``order`` (identity-padded) is a permutation of the padded range, so
+    every output slot is written exactly once."""
+    R, W = order_ref.shape
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, _LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, _LANES), 1)
+    tile = _ROWS * _LANES
 
-    def body(i, _):
-        o = order_ref[0, i]
-        val = jnp.where(sent_ref[0, i] != 0, zero, f_ref[0, i][None])
-        out_ref[pl.ds(o, 1)] = val
-        return 0
+    def body(i, carry):
+        r, c = i // W, i % W
+        o = order_ref[r, c]
+        val = jnp.where(sent_ref[r, c] != 0, jnp.zeros((), out_ref.dtype),
+                        f_ref[r, c])
+        t = o // tile
+        hit = (sub == (o % tile) // _LANES) & (lane == o % _LANES)
+        out_ref[t] = jnp.where(hit, val, out_ref[t])
+        return carry
 
-    jax.lax.fori_loop(0, L, body, 0)
+    jax.lax.fori_loop(0, R * W, body, 0)
 
 
-def scatter_finish_v2(f, order, is_sentinel, *, chunk: int = 128,
+def scatter_finish_v2(f, order, is_sentinel, *,
                       interpret: Optional[bool] = None,
                       force_pallas: bool = False):
     """Scatter sorted results back to original rows with the sentinel mask
     folded in: returns ``out`` with ``out[order[i]] = 0 if is_sentinel[i]
     else f[i]`` — bitwise the lax ``where`` + ``.at[order].set`` epilogue,
-    in one pass.  ``order`` must be a permutation of ``range(len(f))``."""
+    in one pass.  ``order`` must be a permutation of ``range(len(f))``.
+    The compiled kernel keeps the whole output in VMEM (4 bytes per row,
+    under the default scoped limit up to 2**20 rows)."""
     C = f.shape[0]
     if C == 0:
         return f
@@ -210,28 +231,27 @@ def scatter_finish_v2(f, order, is_sentinel, *, chunk: int = 128,
         masked = jnp.where(is_sentinel, jnp.zeros((), f.dtype), f)
         return jnp.zeros((C,), f.dtype).at[order].set(masked)
 
-    L = min(chunk, _pow2_ceil(C))
-    pad = (-C) % L
+    tile = _ROWS * _LANES
+    pad = (-C) % tile
     if pad:        # identity-pad the permutation; padded rows write 0
         tail = jnp.arange(C, C + pad, dtype=order.dtype)
         order = jnp.concatenate([order, tail])
         f = jnp.concatenate([f, jnp.zeros((pad,), f.dtype)])
         is_sentinel = jnp.concatenate(
             [is_sentinel, jnp.ones((pad,), is_sentinel.dtype)])
-    C_pad = C + pad
-    nc = C_pad // L
+    n_tiles = (C + pad) // tile
+    rows = (C + pad) // _LANES
+    spec = pl.BlockSpec((_ROWS, _LANES), lambda c: (c, 0),
+                        memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         _scatter_kernel,
-        grid=(nc,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda c: (c, 0)),
-            pl.BlockSpec((1, L), lambda c: (c, 0)),
-            pl.BlockSpec((1, L), lambda c: (c, 0)),
-        ],
-        out_specs=pl.BlockSpec((C_pad,), lambda c: (0,)),
-        out_shape=jax.ShapeDtypeStruct((C_pad,), f.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        grid=(n_tiles,),
+        in_specs=[spec, spec, spec],
+        out_specs=pl.BlockSpec((n_tiles, _ROWS, _LANES), lambda c: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, _ROWS, _LANES), f.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret and force_pallas,
-    )(f.reshape(nc, L), order.astype(jnp.int32).reshape(nc, L),
-      is_sentinel.astype(jnp.int32).reshape(nc, L))
-    return out[:C]
+    )(f.reshape(rows, _LANES), order.astype(jnp.int32).reshape(rows, _LANES),
+      is_sentinel.astype(jnp.int32).reshape(rows, _LANES))
+    return out.reshape(-1)[:C]

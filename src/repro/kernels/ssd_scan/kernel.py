@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref):
     ci = pl.program_id(1)
@@ -87,7 +85,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, c: (b, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, nc, chunk, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xr, dtr, A, Br, Cr)

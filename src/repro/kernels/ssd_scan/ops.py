@@ -3,17 +3,16 @@ import functools
 
 import jax
 
+from repro.core.compat import resolve_kernel_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd(x, dt, A, B, C, chunk):
-    return ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=not _on_tpu())
+    return ssd_scan(x, dt, A, B, C, chunk=chunk,
+                    interpret=resolve_kernel_interpret(
+                        None, warn=False, context="ssd_scan"))
 
 
 def _ssd_fwd(x, dt, A, B, C, chunk):

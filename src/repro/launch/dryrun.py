@@ -169,9 +169,8 @@ def pp_smoke(out_dir=None):
     pipelined over mesh (4,8,16) = ("pipe","data","model") — 512 chips."""
     import jax.numpy as _jnp
     from repro.train.pipeline import pipelined_apply
-    from repro.core.compat import AXIS_TYPE_AUTO, make_mesh
-    mesh = make_mesh((4, 8, 16), ("pipe", "data", "model"),
-                     axis_types=(AXIS_TYPE_AUTO,) * 3)
+    mesh = jax.make_mesh((4, 8, 16), ("pipe", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     L, B, S, D, F = 32, 64, 4096, 4096, 14336
 
     def layer_fn(p, h):

@@ -11,21 +11,20 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import AXIS_TYPE_AUTO, make_mesh
 from repro.models.param import axis_rules, resolve_shardings, resolve_spec
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AXIS_TYPE_AUTO,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over whatever devices exist (tests / local runs)."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AXIS_TYPE_AUTO,) * 2)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 # ------------------------------------------------------------- sharding trees

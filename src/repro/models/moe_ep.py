@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
-
 from repro.models.moe import matchmaking_route
 from repro.models.shard_ctx import current_rules
 
@@ -117,8 +115,8 @@ def moe_block_ep(params, x, cfg, *, compute_dtype=jnp.bfloat16):
         y = jax.lax.psum(y, "model")
         return y.reshape(Bl, Sl, D)
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(x_spec, r_spec, w_spec, w_spec, wo_spec),
-                  out_specs=x_spec, check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(x_spec, r_spec, w_spec, w_spec, wo_spec),
+                      out_specs=x_spec, check_vma=False)
     return f(x, params["w_router"], params["we_gate"], params["we_in"],
              params["we_out"])

@@ -1,11 +1,12 @@
 """Roofline analysis over dry-run artifacts.
 
-Hardware model (TPU v5e per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI, 16 GiB HBM.  Terms per (arch × shape × mesh) cell:
+Hardware model: the per-chip peaks of ``PEAKS``, keyed by
+``jax.Device.device_kind``.  Dry-run cells (production meshes of v5e) use
+the v5e entry.  Terms per (arch × shape × mesh) cell:
 
-  t_comp = parsed_FLOPs_per_device / PEAK_FLOPS
-  t_mem  = parsed_HBM_bytes_per_device / HBM_BW
-  t_coll = parsed_collective_bytes_per_device / LINK_BW
+  t_comp = parsed_FLOPs_per_device / peak FLOP/s
+  t_mem  = parsed_HBM_bytes_per_device / HBM bytes/s
+  t_coll = parsed_collective_bytes_per_device / link bytes/s
 
 The bottleneck is the max term; roofline fraction = t_comp / max(terms)
 (the share of the step the MXUs could actually be busy).  MODEL_FLOPS
@@ -18,39 +19,61 @@ import dataclasses
 import glob
 import json
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from repro.configs import SHAPES, get_config
 from repro.roofline.hlo_parse import analyze
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link (ICI, conservative single link)
-HBM_CAP = 16 * 2 ** 30
 
-# Host-CPU fallbacks (per core, conservative): used by the seg-scan
-# autotuner so rankings computed off-TPU still carry meaningful bottleneck
-# labels.  Rankings only compare candidates against each other, so only the
-# flops:bandwidth RATIO matters for the chosen chunk.
-CPU_PEAK_FLOPS = 5e10
-CPU_MEM_BW = 2e10
-CPU_LINK_BW = 1e10
+class Peaks(NamedTuple):
+    flops: float             # FLOP/s per chip (bf16 for TPUs)
+    mem_bw: float            # memory bytes/s per chip
+    link_bw: float           # bytes/s per interconnect link
+    mem_cap: float           # memory bytes per chip
+    source: str
 
 
-def hw_constants(backend: Optional[str] = None) -> Tuple[float, float, float]:
-    """(peak_flops, mem_bw, link_bw) for a backend name ('tpu' or host)."""
-    if backend == "tpu":
-        return PEAK_FLOPS, HBM_BW, LINK_BW
-    return CPU_PEAK_FLOPS, CPU_MEM_BW, CPU_LINK_BW
+V5E = "TPU v5 lite"          # jax's device_kind for a TPU v5e chip
+
+PEAKS: Dict[str, Peaks] = {
+    # 1,600 Gbit/s of ICI per chip over 4 links: 50 GB/s per link
+    V5E: Peaks(197e12, 819e9, 50e9, 16 * 2 ** 30,
+               'Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '819 GB/s HBM, 16 GB HBM, 1,600 Gbit/s ICI'),
+    # not a device peak: nominal per-core host figures so the seg-scan
+    # autotuner can rank candidates in CPU test runs, where only the
+    # flops:bandwidth RATIO decides the chosen chunk
+    "cpu": Peaks(5e10, 2e10, 1e10, 64 * 2 ** 30,
+                 "nominal host CPU core, for ranking in tests only"),
+}
 
 
-def roofline_terms(costs, backend: Optional[str] = None
+def peaks(device_kind: str) -> Peaks:
+    """The peak table entry for a ``jax.Device.device_kind``.  A kind that
+    is not in the table is an error: a roofline against another chip's
+    peaks would be a wrong number, not an estimate."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it "
+            f"to repro.roofline.analysis.PEAKS with its source") from None
+
+
+def local_device_kind() -> str:
+    """``device_kind`` of the first local device — what this process runs
+    on, and the key the autotuner persists its choices under."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def roofline_terms(costs, device_kind: Optional[str] = None
                    ) -> Tuple[float, float, float, str]:
     """(t_comp, t_mem, t_coll, bottleneck) for a ``hlo_parse.Costs`` — the
     same max-term model ``analyze_cell`` applies to dry-run artifacts,
     reusable on directly-parsed (or analytically-modelled) costs.  This is
     what the seg-scan chunk autotuner ranks candidates with."""
-    peak, mem_bw, link_bw = hw_constants(backend)
+    peak, mem_bw, link_bw, _, _ = peaks(device_kind or local_device_kind())
     t_comp = costs.flops / peak
     t_mem = costs.hbm_bytes / mem_bw
     t_coll = costs.coll_bytes / link_bw
@@ -125,12 +148,8 @@ def analyze_cell(json_path: str) -> CellRoofline:
     costs = analyze(txt, branch_weights=branch_weights_for(arch))
     n_dev = 512 if mesh == "pod2" else 256
 
-    t_comp = costs.flops / PEAK_FLOPS
-    t_mem = costs.hbm_bytes / HBM_BW
-    t_coll = costs.coll_bytes / LINK_BW
-    terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
-    bottleneck = max(terms, key=terms.get)
-    t_max = max(terms.values()) or 1e-30
+    t_comp, t_mem, t_coll, bottleneck = roofline_terms(costs, V5E)
+    t_max = max(t_comp, t_mem, t_coll) or 1e-30
 
     mf = model_flops_for(arch, shape)
     parsed_total = costs.flops * n_dev
@@ -144,7 +163,7 @@ def analyze_cell(json_path: str) -> CellRoofline:
         roofline_fraction=t_comp / t_max,
         model_flops=mf, useful_ratio=mf / parsed_total if parsed_total else 0.0,
         peak_gb=meta.get("peak_gb", 0.0),
-        fits_hbm=meta.get("peak_gb", 0.0) <= HBM_CAP / 2 ** 30,
+        fits_hbm=meta.get("peak_gb", 0.0) <= PEAKS[V5E].mem_cap / 2 ** 30,
         meta=meta)
 
 

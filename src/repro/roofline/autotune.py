@@ -15,14 +15,15 @@ pick them:
      jnp tail passes, so larger L trades VMEM scratch for fewer full-array
      round trips; the v1 matmul kernel pays 2·C·L MXU FLOPs instead.
   3. **Rank** — ``analysis.roofline_terms`` turns each candidate's costs
-     into max(t_comp, t_mem) seconds for the backend; the analytic winner
+     into max(t_comp, t_mem) seconds on the device kind's peaks (an
+     unknown kind is an error); the analytic winner
      is the lowest (``rank_chunks``).
   4. **Confirm** — ``tuned_chunk(measure=True)`` microbenchmarks the top
      analytic candidates PLUS the hand-picked default and keeps the argmin,
      so the tuned choice is never slower than the default on the harness
      (the default is always in the measured set).
 
-Choices persist per (backend, kind, pow2 size bucket) in a ``CompileCache``
+Choices persist per (device_kind, kernel kind, pow2 size bucket) in a ``CompileCache``
 (``TUNE_CACHE``), so the in-library resolution des_scan performs at trace
 time (``tuned_chunk(C)`` with ``measure=False``) is a pure cache lookup or
 closed-form ranking — it never compiles or times anything inside a trace.
@@ -47,7 +48,7 @@ DEFAULT_CHUNK = 128          # the hand-picked pre-autotuner constant
 _F32 = 4                     # bytes
 _PROXY_C = 4096              # HLO-parse anchor size (compiles in ~100 ms)
 
-# (backend, kind, pow2_ceil(C)) -> TuningChoice.  A CompileCache for the
+# (device_kind, kind, pow2_ceil(C)) -> TuningChoice.  A CompileCache for the
 # LRU + stats plumbing; entries are metadata, so puts use count_build=False.
 TUNE_CACHE = CompileCache(max_entries=64)
 
@@ -74,7 +75,7 @@ class ChunkScore:
 class TuningChoice:
     chunk: int
     kind: str                # "v1" | "v2"
-    backend: str
+    device_kind: str
     source: str              # "analytic" | "measured"
     scores: Tuple[ChunkScore, ...]        # analytic ranking, best first
     measured_s: Dict[int, float]          # chunk -> best-of-N seconds
@@ -145,17 +146,18 @@ def kernel_costs(C: int, chunk: int, kind: str = "v2") -> Costs:
     return costs
 
 
-def rank_chunks(C: int, kind: str = "v2", backend: Optional[str] = None,
+def rank_chunks(C: int, kind: str = "v2",
+                device_kind: Optional[str] = None,
                 candidates: Optional[Iterable[int]] = None
                 ) -> Tuple[ChunkScore, ...]:
     """Candidates scored by the analytic roofline, fastest first (ties to
     the smaller chunk — less VMEM scratch for the same modelled time)."""
-    backend = backend or jax.default_backend()
+    device_kind = device_kind or analysis.local_device_kind()
     scores = []
     for c in (candidates or candidate_chunks(C)):
         costs = kernel_costs(C, c, kind)
         t_comp, t_mem, _, bottleneck = analysis.roofline_terms(
-            costs, backend=backend)
+            costs, device_kind)
         scores.append(ChunkScore(chunk=int(c), t_model=max(t_comp, t_mem),
                                  bottleneck=bottleneck, flops=costs.flops,
                                  hbm_bytes=costs.hbm_bytes))
@@ -170,20 +172,17 @@ def _default_bench(C: int, kind: str) -> Callable[[int], float]:
     the emulation/interpreter fallback elsewhere) — the same path des_scan
     will take, which is the honest thing to confirm against."""
     rng = np.random.default_rng(0)
-    # v1 runs under the Pallas interpreter off-TPU: cap the bench size so a
+    # v1 runs only under the Pallas interpreter: cap the bench size so a
     # tuning pass stays sub-second per candidate
-    Cb = int(C) if (kind == "v2" or jax.default_backend() == "tpu") \
-        else min(int(C), 1 << 14)
+    Cb = int(C) if kind == "v2" else min(int(C), 1 << 14)
     term = jnp.asarray(rng.uniform(0.0, 5.0, Cb).astype(np.float32))
     start = jnp.asarray(rng.uniform(size=Cb) < 0.1)
 
     def bench(chunk: int) -> float:
         if kind == "v1":
-            from repro.core.compat import pallas_interpret_default
             from repro.kernels.seg_scan.kernel import seg_cumsum
             fn = jax.jit(lambda t, s: seg_cumsum(
-                t, s.astype(jnp.float32), chunk=chunk,
-                interpret=pallas_interpret_default()))
+                t, s.astype(jnp.float32), chunk=chunk, interpret=True))
         else:
             from repro.kernels.seg_scan.v2 import seg_cumsum_v2
             fn = jax.jit(lambda t, s: seg_cumsum_v2(t, s, chunk=chunk))
@@ -198,7 +197,8 @@ def _default_bench(C: int, kind: str) -> Callable[[int], float]:
     return bench
 
 
-def tuned_chunk(C: int, *, kind: str = "v2", backend: Optional[str] = None,
+def tuned_chunk(C: int, *, kind: str = "v2",
+                device_kind: Optional[str] = None,
                 measure: bool = False,
                 bench: Optional[Callable[[int], float]] = None,
                 candidates: Optional[Sequence[int]] = None,
@@ -206,20 +206,21 @@ def tuned_chunk(C: int, *, kind: str = "v2", backend: Optional[str] = None,
     """The tuned ``chunk`` for a size-``C`` seg-scan.
 
     ``measure=False`` (the in-library default — des_scan calls this at
-    TRACE time) returns the persisted choice for the (backend, kind, pow2
+    TRACE time) returns the persisted choice for the (device_kind, kind, pow2
     size bucket), falling back to the analytic roofline winner; nothing is
     compiled or timed.  ``measure=True`` confirms the top ``top_k``
     analytic candidates + the hand-picked default on the microbench and
     persists the argmin — since the default is always measured, the tuned
     choice can never be slower than it on the harness."""
-    backend = backend or jax.default_backend()
-    key = (backend, kind, _pow2_ceil(max(int(C), 1)))
+    device_kind = device_kind or analysis.local_device_kind()
+    key = (device_kind, kind, _pow2_ceil(max(int(C), 1)))
     hit = TUNE_CACHE.get(key)
     if hit is not None and (hit.source == "measured" or not measure):
         return hit.chunk
 
-    scores = rank_chunks(C, kind, backend, candidates)
-    choice = TuningChoice(chunk=scores[0].chunk, kind=kind, backend=backend,
+    scores = rank_chunks(C, kind, device_kind, candidates)
+    choice = TuningChoice(chunk=scores[0].chunk, kind=kind,
+                          device_kind=device_kind,
                           source="analytic", scores=scores, measured_s={})
     if measure:
         bench = bench or _default_bench(C, kind)
@@ -236,16 +237,17 @@ def tuned_chunk(C: int, *, kind: str = "v2", backend: Optional[str] = None,
 
 
 def tuning_report(C: int, kind: str = "v2",
-                  backend: Optional[str] = None) -> Optional[TuningChoice]:
+                  device_kind: Optional[str] = None
+                  ) -> Optional[TuningChoice]:
     """Peek the persisted choice for a size bucket without ranking."""
-    backend = backend or jax.default_backend()
-    return TUNE_CACHE.get((backend, kind, _pow2_ceil(max(int(C), 1))))
+    device_kind = device_kind or analysis.local_device_kind()
+    return TUNE_CACHE.get((device_kind, kind, _pow2_ceil(max(int(C), 1))))
 
 
 # --------------------------------------------------- exchange block policy
 
-def tuned_exchange_block(C: int, n_members: int, *, slack: float = 1.25,
-                         backend: Optional[str] = None) -> int:
+def tuned_exchange_block(C: int, n_members: int, *,
+                         slack: float = 1.25) -> int:
     """Analytic exchange ``block`` (per-(src, dst) all-to-all capacity) for
     the distributed core: the expected balanced load is C/M² entries, the
     slack absorbs ownership skew, and the result is pow2-rounded so the
@@ -262,7 +264,7 @@ def tuned_exchange_block(C: int, n_members: int, *, slack: float = 1.25,
 
 
 def exchange_roofline(C: int, n_members: int, block: int,
-                      backend: Optional[str] = None) -> Tuple[float, str]:
+                      device_kind: Optional[str] = None) -> Tuple[float, str]:
     """Modelled (seconds, bottleneck) of one exchange at a given block:
     the padded all-to-all ships M·block triples of 16 bytes per member and
     the local scan covers ~C/M elements — the roofline view of why
@@ -274,5 +276,5 @@ def exchange_roofline(C: int, n_members: int, block: int,
     costs.flops = float(local * _n_steps(local))
     costs.hbm_bytes = float(local * 3 * _F32 * max(_n_steps(local), 1))
     t_comp, t_mem, t_coll, bottleneck = analysis.roofline_terms(
-        costs, backend=backend or jax.default_backend())
+        costs, device_kind)
     return max(t_comp, t_mem, t_coll), bottleneck

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
-
 
 # ----------------------------------------------------- int8 error feedback
 
@@ -89,5 +87,5 @@ def ring_reduce_scatter(x, mesh: Mesh, axis: str = "data"):
         buf = jax.lax.fori_loop(1, n, step, buf)
         return buf[None]                              # (1, k) per member
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                     out_specs=P(axis), check_vma=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(axis), check_vma=False)(x)

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
-
 
 def pipelined_apply(layer_fn: Callable, stacked_params, x, mesh: Mesh, *,
                     n_microbatch: int, data_axes=("data",)):
@@ -80,6 +78,6 @@ def pipelined_apply(layer_fn: Callable, stacked_params, x, mesh: Mesh, *,
     p_spec = jax.tree_util.tree_map(
         lambda l: P("pipe", *([None] * (l.ndim - 1))), stacked_params)
     x_spec = P(data_axes, None, None)
-    f = shard_map(body, mesh=mesh, in_specs=(p_spec, x_spec),
-                  out_specs=x_spec, check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(p_spec, x_spec),
+                      out_specs=x_spec, check_vma=False)
     return f(stacked_params, x)

@@ -403,6 +403,19 @@ def test_auto_block_cache_writes_only_on_measurement():
     des_scan.invalidate_dist_core()
 
 
+def test_start_members_beyond_the_pool_is_refused():
+    """Asking for more members than devices is an error, never a silent
+    clamp to the devices present."""
+    import jax
+
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="start_members"):
+        ElasticDispatcher(start_members=n + 1)
+    with pytest.raises(ValueError, match="start_members"):
+        ElasticDispatcher(start_members=0)
+    assert ElasticDispatcher(start_members=n).n_members == n
+
+
 def test_cluster_rejects_conflicting_topology_kwargs():
     from repro.core.cloudsim import ElasticSimulationCluster
 

@@ -61,9 +61,8 @@ from repro.configs import get_config, reduced
 from repro.models import moe as moe_mod
 from repro.models.shard_ctx import sharding_rules
 from repro.models.param import init_params
-from repro.core.compat import AXIS_TYPE_AUTO, make_mesh
-mesh = make_mesh((2,2), ("data","model"),
-                 axis_types=(AXIS_TYPE_AUTO,)*2)
+mesh = jax.make_mesh((2,2), ("data","model"),
+                     axis_types=(jax.sharding.AxisType.Auto,)*2)
 cfg = dataclasses.replace(reduced(get_config("olmoe-1b-7b"), n_experts=4,
                                   d_ff_expert=64, d_model=64),
                           capacity_factor=8.0)
@@ -140,8 +139,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.attention import _chunked_attn
 
-from repro.core.compat import AXIS_TYPE_AUTO, make_mesh
-mesh = make_mesh((4,), ("data",), axis_types=(AXIS_TYPE_AUTO,))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 B, S, H, hd = 1, 256, 2, 16
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)

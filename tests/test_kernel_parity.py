@@ -89,8 +89,7 @@ def test_scatter_finish_v2_bitwise_both_paths():
         want[np.asarray(order)] = np.where(np.asarray(sent), 0.0,
                                            np.asarray(f))
         for kw in (dict(interpret=True), dict(force_pallas=True)):
-            got = np.asarray(scatter_finish_v2(f, order, sent, chunk=64,
-                                               **kw))
+            got = np.asarray(scatter_finish_v2(f, order, sent, **kw))
             assert np.array_equal(want, got), (C, kw)
 
 
@@ -220,11 +219,11 @@ def test_candidate_chunks_clamped_and_default_present():
 
 def test_analytic_ranking_models_both_kernels():
     # v2 is memory-bound at 1M: bigger L -> fewer tail passes -> wins
-    v2 = autotune.rank_chunks(1 << 20, kind="v2", backend="cpu")
+    v2 = autotune.rank_chunks(1 << 20, kind="v2", device_kind="cpu")
     assert v2[0].chunk == max(s.chunk for s in v2)
     assert v2[0].bottleneck == "memory"
     # v1's masked matmul makes FLOPs grow with L: smallest chunk wins
-    v1 = autotune.rank_chunks(1 << 20, kind="v1", backend="cpu")
+    v1 = autotune.rank_chunks(1 << 20, kind="v1", device_kind="cpu")
     assert v1[0].chunk == min(s.chunk for s in v1)
     # the measured HLO anchor parses real compiled traffic (the add-only
     # scan has no dot ops, so only HBM bytes are nonzero — memory-bound)
@@ -232,41 +231,56 @@ def test_analytic_ranking_models_both_kernels():
     assert costs.hbm_bytes > 0
     small = autotune.lax_scan_costs(1 << 12)
     assert costs.hbm_bytes > small.hbm_bytes    # element·step extrapolation
+    # peaks are keyed by device_kind; a kind without published peaks is an
+    # error, never a default
+    assert autotune.rank_chunks(1 << 20, device_kind="TPU v5 lite")
+    with pytest.raises(ValueError, match="no published peaks"):
+        autotune.rank_chunks(1 << 20, device_kind="TPU v99")
 
 
-def test_tuned_chunk_never_slower_than_default():
+@pytest.fixture
+def fresh_tune_cache(monkeypatch):
+    """Each autotuner test persists into its own empty cache."""
+    from repro.core.dispatch import CompileCache
+    monkeypatch.setattr(autotune, "TUNE_CACHE", CompileCache(max_entries=64))
+
+
+def test_tuned_chunk_never_slower_than_default(fresh_tune_cache):
     """With measure=True the hand-picked default is ALWAYS in the measured
     set, so the returned chunk's measured time <= the default's."""
     fake = {64: 3e-3, 128: 2e-3, 256: 1e-3, 512: 4e-3, 1024: 5e-3}
-    got = autotune.tuned_chunk(1 << 20, backend="fake-a", measure=True,
+    got = autotune.tuned_chunk(1 << 20, device_kind="cpu", measure=True,
                                bench=lambda c: fake[c], top_k=2)
-    choice = autotune.tuning_report(1 << 20, backend="fake-a")
+    choice = autotune.tuning_report(1 << 20, device_kind="cpu")
     assert choice.source == "measured"
     assert autotune.DEFAULT_CHUNK in choice.measured_s
     assert choice.measured_s[got] <= choice.measured_s[autotune.DEFAULT_CHUNK]
     # when the default measures fastest, it IS the answer (ties included)
-    got2 = autotune.tuned_chunk(1 << 19, backend="fake-b", measure=True,
+    got2 = autotune.tuned_chunk(1 << 19, device_kind="cpu", measure=True,
                                 bench=lambda c: 1e-3 if c == 128 else 9e-3)
     assert got2 == autotune.DEFAULT_CHUNK
 
 
-def test_tuned_chunk_trace_time_purity_and_cache():
+def test_tuned_chunk_trace_time_purity_and_cache(fresh_tune_cache):
     """measure=False never benches (a poisoned bench proves it) and the
-    measured choice persists per (backend, kind, pow2 bucket)."""
+    measured choice persists per (device_kind, kind, pow2 bucket)."""
     def boom(c):
         raise AssertionError("measure=False must not bench")
 
-    got = autotune.tuned_chunk(1 << 18, backend="fake-c", bench=boom)
-    assert got == autotune.rank_chunks(1 << 18, backend="fake-c")[0].chunk
-    autotune.tuned_chunk(1 << 18, backend="fake-c", measure=True,
+    got = autotune.tuned_chunk(1 << 18, device_kind="cpu", bench=boom)
+    assert got == autotune.rank_chunks(1 << 18, device_kind="cpu")[0].chunk
+    autotune.tuned_chunk(1 << 18, device_kind="cpu", measure=True,
                          bench=lambda c: {64: 9, 128: 9}.get(c, 1e-4))
     # cache hit: measured choice now wins even with a poisoned bench
-    again = autotune.tuned_chunk(1 << 18, backend="fake-c", bench=boom,
+    again = autotune.tuned_chunk(1 << 18, device_kind="cpu", bench=boom,
                                  measure=True)
-    assert again == autotune.tuning_report(1 << 18, backend="fake-c").chunk
+    assert again == autotune.tuning_report(1 << 18, device_kind="cpu").chunk
     # same bucket, different size -> same cached entry
-    assert autotune.tuned_chunk((1 << 18) - 3, backend="fake-c",
+    assert autotune.tuned_chunk((1 << 18) - 3, device_kind="cpu",
                                 bench=boom) == again
+    # another chip generation keeps its own entry
+    assert autotune.tuning_report(1 << 18,
+                                  device_kind="TPU v5 lite") is None
 
 
 def test_tuned_exchange_block_bounds():
@@ -287,6 +301,11 @@ def test_kernel_path_resolution():
     assert compat.kernel_path(True, interpret=False) == "compiled"
     on_cpu = "interpret" if jax.default_backend() != "tpu" else "compiled"
     assert compat.kernel_path(True) == on_cpu
+    # a backend that can neither compile nor interpret-by-default is refused
+    from unittest import mock
+    with mock.patch.object(jax, "default_backend", return_value="gpu"):
+        with pytest.raises(RuntimeError, match="no path on backend"):
+            compat.resolve_kernel_interpret(None)
 
 
 def test_interpret_fallback_warns_exactly_once(monkeypatch):

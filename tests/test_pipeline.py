@@ -19,10 +19,9 @@ def test_gpipe_matches_sequential():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh
 from repro.train.pipeline import pipelined_apply
-from repro.core.compat import AXIS_TYPE_AUTO, make_mesh
 
-mesh = make_mesh((2, 2), ("pipe", "data"),
-                 axis_types=(AXIS_TYPE_AUTO,)*2)
+mesh = jax.make_mesh((2, 2), ("pipe", "data"),
+                     axis_types=(jax.sharding.AxisType.Auto,)*2)
 L, B, S, D = 4, 8, 4, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (L, D, D)) / jnp.sqrt(D)
