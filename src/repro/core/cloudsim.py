@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Dict, Optional
 
 import jax
@@ -45,6 +44,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.executor import DistributedExecutor
 from repro.core.grid import DataGrid
 from repro.core.partition import pad_to_shards
+from repro.core.spans import span
 from repro.core import des_scan
 
 
@@ -291,41 +291,42 @@ def run_simulation(cfg: SimulationConfig, mesh: Mesh,
     executor = executor if executor is not None else DistributedExecutor(mesh)
     timings = {}
 
-    t0 = time.perf_counter()
-    ents = create_entities(cfg, grid, pad_multiple)
-    jax.block_until_ready(grid.get("cloudlet_mi"))
-    timings["create"] = time.perf_counter() - t0
+    with span("sim.create") as stage:
+        create_entities(cfg, grid, pad_multiple)
+        jax.block_until_ready(grid.get("cloudlet_mi"))
+    timings["create"] = stage.seconds
 
-    t0 = time.perf_counter()
-    assign = schedule(cfg, grid, executor)
-    jax.block_until_ready(assign)
-    timings["schedule"] = time.perf_counter() - t0
+    with span("sim.schedule") as stage:
+        assign = schedule(cfg, grid, executor)
+        jax.block_until_ready(assign)
+    timings["schedule"] = stage.seconds
 
     checks = None
     if cfg.is_loaded:
-        t0 = time.perf_counter()
-        checks = run_workloads(cfg, grid, executor)
-        jax.block_until_ready(checks)
-        timings["workload"] = time.perf_counter() - t0
+        with span("sim.workload") as stage:
+            checks = run_workloads(cfg, grid, executor)
+            jax.block_until_ready(checks)
+        timings["workload"] = stage.seconds
 
-    t0 = time.perf_counter()
-    core_args = (assign, grid.get("cloudlet_mi"), grid.get("vm_mips"),
-                 grid.get("cloudlet_valid"))
-    if cfg.core == "wave":
-        finish, makespan = _simulate_completion_jit(*core_args)
-    elif cfg.core == "scan_dist":
-        finish, makespan = des_scan.simulate_completion_distributed(
-            *core_args, executor, vm_owner=vm_owner, method=cfg.dist_method,
-            slack=cfg.exchange_slack, use_kernel=cfg.use_kernel,
-            kernel_chunk=cfg.kernel_chunk, weight_observer=weight_observer)
-    elif cfg.core == "scan":
-        finish, makespan = des_scan.simulate_completion_scan_jit(
-            *core_args, use_kernel=cfg.use_kernel,
-            kernel_chunk=cfg.kernel_chunk)
-    else:
-        raise ValueError(f"unknown core {cfg.core!r}")
-    jax.block_until_ready(finish)
-    timings["core_sim"] = time.perf_counter() - t0
+    with span("sim.core") as stage:
+        core_args = (assign, grid.get("cloudlet_mi"), grid.get("vm_mips"),
+                     grid.get("cloudlet_valid"))
+        if cfg.core == "wave":
+            finish, makespan = _simulate_completion_jit(*core_args)
+        elif cfg.core == "scan_dist":
+            finish, makespan = des_scan.simulate_completion_distributed(
+                *core_args, executor, vm_owner=vm_owner,
+                method=cfg.dist_method, slack=cfg.exchange_slack,
+                use_kernel=cfg.use_kernel, kernel_chunk=cfg.kernel_chunk,
+                weight_observer=weight_observer)
+        elif cfg.core == "scan":
+            finish, makespan = des_scan.simulate_completion_scan_jit(
+                *core_args, use_kernel=cfg.use_kernel,
+                kernel_chunk=cfg.kernel_chunk)
+        else:
+            raise ValueError(f"unknown core {cfg.core!r}")
+        jax.block_until_ready(finish)
+    timings["core_sim"] = stage.seconds
 
     if own_grid:
         grid.clear()   # clearDistributedObjects()

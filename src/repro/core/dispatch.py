@@ -80,7 +80,9 @@ import collections
 import contextlib
 import dataclasses
 import functools
+import itertools
 import math
+import re
 import signal as _signal
 import threading
 import time
@@ -102,6 +104,7 @@ from repro.core.journal import (CheckpointPolicy, DrainInterrupted,
                                 stable_signature, tree_digest)
 from repro.core.partition import (DEFAULT_PARTITION_COUNT, PartitionTable,
                                   pad_to_shards, partition_weights_from_keys)
+from repro.core.spans import jax_counts, span
 from repro.core.stats import DispatchStats, QueueSnapshot
 
 
@@ -116,6 +119,18 @@ class NonPow2ChunkWarning(UserWarning):
 # --------------------------------------------------------------- compile cache
 
 _MISSING = object()
+_STREAM_IDS = itertools.count()      # the ``stream`` arg of a submit's spans
+
+
+def _named(fn: Callable, job: DispatchJob, stage: str = "") -> Callable:
+    """Name ``fn`` after ``job`` (and ``stage``) so the module ``jax.jit``
+    compiles from it is ``jit_dispatch_<job>[_<stage>]`` on the device
+    trace: ``mapreduce/word_count`` -> ``jit_dispatch_mapreduce_word_count``.
+    """
+    name = "dispatch_" + re.sub(r"\W", "_", job.name) + (
+        f"_{stage}" if stage else "")
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class CompileCache:
@@ -258,6 +273,14 @@ def _row_tree_sum(rows, valid):
     while x.shape[0] > 1:
         x = x[0::2] + x[1::2]
     return x[0]
+
+
+def _row_tree_jit(job: DispatchJob):
+    """``_row_tree_sum`` over every leaf of ``job``'s per-row output, as an
+    executable of its own (see ``_row_tree_sum``) named ``..._tree``."""
+    def tree(out, valid):
+        return jax.tree_util.tree_map(lambda a: _row_tree_sum(a, valid), out)
+    return jax.jit(_named(tree, job, "tree"))
 
 
 def _chunk_tree_reduce(parts, combine, pending=None):
@@ -455,6 +478,12 @@ class DispatchReport:
     # throughput, utilization, mean queue length) — see repro/core/stats.py
     # and docs/observability.md.  None when instrumentation is off.
     stats: Optional[dict] = None
+    # what JAX did on this stream's thread while it ran (``core.spans.
+    # jax_counts``): jaxprs traced, programs XLA compiled, and programs
+    # loaded from the persistent compilation cache instead of compiled
+    jax_traces: int = 0
+    jax_compiles: int = 0
+    jax_cache_loads: int = 0
 
     def summary(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -1024,6 +1053,41 @@ class ElasticDispatcher:
         # outputs trim to correct empty arrays, sum/max partials reduce over
         # masked-out rows only — parity with the non-dispatcher vmap path
         n_chunks = max(-(-B // chunk), 1)
+        # per-stage queueing stats: enqueue → dispatch → retire → validate
+        # stamps per chunk, plus the stage spans.  Collection never touches
+        # chunk payloads or reduce order (results stay bit-identical); the
+        # mmn policy depends on the measured service decomposition, so it
+        # forces a collector.
+        collect = (self.collect_stats if collect_stats is None
+                   else collect_stats)
+        collector = (DispatchStats(warmup=self.health_cfg.stats_warmup,
+                                   cooldown=self.health_cfg.stats_cooldown)
+                     if collect or self.health_cfg.policy == "mmn" else None)
+        sid = next(_STREAM_IDS)
+        jax0 = jax_counts()
+        with span("dispatch.stream", collector, job=job.name,
+                  n_chunks=n_chunks, stream=sid):
+            outputs, report = self._stream(
+                job, items, leaves, B, chunk, n_chunks, collector, sid,
+                replicated=replicated, on_chunk=on_chunk,
+                dispatch_ahead=dispatch_ahead, deliver=deliver,
+                retry_policy=retry_policy, fault_injector=fault_injector,
+                checkpoint=checkpoint, _resume=_resume)
+        jax1 = jax_counts()
+        report.jax_traces = jax1["traces"] - jax0["traces"]
+        report.jax_compiles = jax1["compiles"] - jax0["compiles"]
+        report.jax_cache_loads = jax1["cache_loads"] - jax0["cache_loads"]
+        if collector is not None:            # with the stream's own span
+            report.stats = collector.summary(n_servers=1)
+        return outputs, report
+
+    def _stream(self, job: DispatchJob, items, leaves, B: int, chunk: int,
+                n_chunks: int, collector: Optional[DispatchStats], sid: int,
+                *, replicated, on_chunk, dispatch_ahead, deliver,
+                retry_policy, fault_injector, checkpoint, _resume
+                ) -> Tuple[object, DispatchReport]:
+        """The body of ``submit``'s chunk stream (``sid``: its span id),
+        inside its ``dispatch.stream`` span."""
         depth = (self.dispatch_ahead if dispatch_ahead is None
                  else max(int(dispatch_ahead), 0))
         # device-side chunk slicing pays one extra jit dispatch per chunk to
@@ -1051,16 +1115,7 @@ class ElasticDispatcher:
             # default attempt budget with the finiteness probe armed
             policy = RetryPolicy(check_finite=injector is not None)
         guarded = injector is not None or policy.active
-        # per-stage queueing stats: enqueue → dispatch → retire → validate
-        # stamps per chunk.  Collection never touches chunk payloads or
-        # reduce order (results stay bit-identical); the mmn policy depends
-        # on the measured service decomposition, so it forces a collector.
-        collect = (self.collect_stats if collect_stats is None
-                   else collect_stats)
         mmn = self.health_cfg.policy == "mmn"
-        collector = (DispatchStats(warmup=self.health_cfg.stats_warmup,
-                                   cooldown=self.health_cfg.stats_cooldown)
-                     if (collect or mmn) else None)
         launch_epoch: Dict[int, int] = {}  # chunk -> epoch at its launch
         if job.deterministic and n_chunks > 1 and chunk & (chunk - 1) != 0:
             warnings.warn(
@@ -1323,7 +1378,8 @@ class ElasticDispatcher:
             """Block on the oldest launched chunk, then sample; the guarded
             path validates every chunk that has left the flight queue."""
             ci, out, compiled, t_launch = self._in_flight.popleft()
-            jax.block_until_ready(out)
+            with span("dispatch.retire", collector, stream=sid, chunk=ci):
+                jax.block_until_ready(out)
             if collector is not None:
                 # stamp BEFORE mark() so the mmn feed sees a fresh mean
                 tainted = compiled or launch_epoch.get(ci) != self._epoch
@@ -1503,26 +1559,30 @@ class ElasticDispatcher:
                     recover_member(e.device, e.member, ci,
                                    cause="member crash detected at launch")
                     return False
-            if on_device:
-                sl, valid = self.executor.slice_chunk(src, lo, L, n_live)
-                report.staged_device += 1
-            else:
-                sl, valid = self._stage_host(items_np, lo, n_live, L)
-                report.staged_host += 1
-            builds_before = self.cache.builds
-            try:
-                if injector is not None:
-                    injector.on_compile(ci)
-                fn = self._executable(job, sl, replicated, L)
-            except CompileFailedError as e:
-                fail_chunk(ci, "compile_fail", detail=str(e))
-                return False
-            compiled_now = self.cache.builds != builds_before
-            t_launch = time.perf_counter()
-            launch_epoch[ci] = self._epoch
-            if collector is not None:
-                collector.dispatch(ci, t_launch, tainted=compiled_now)
-            out = fn(sl, valid, *replicated)         # async dispatch
+            with span("dispatch.stage", collector, stream=sid, chunk=ci):
+                if on_device:
+                    sl, valid = self.executor.slice_chunk(src, lo, L, n_live)
+                    report.staged_device += 1
+                else:
+                    sl, valid = self._stage_host(items_np, lo, n_live, L)
+                    report.staged_host += 1
+            with span("dispatch.launch", collector, stream=sid,
+                      chunk=ci) as launching:
+                builds_before = self.cache.builds
+                try:
+                    if injector is not None:
+                        injector.on_compile(ci)
+                    fn = self._executable(job, sl, replicated, L)
+                except CompileFailedError as e:
+                    fail_chunk(ci, "compile_fail", detail=str(e))
+                    return False
+                compiled_now = self.cache.builds != builds_before
+                launching.annotate(built=int(compiled_now))
+                t_launch = time.perf_counter()
+                launch_epoch[ci] = self._epoch
+                if collector is not None:
+                    collector.dispatch(ci, t_launch, tainted=compiled_now)
+                out = fn(sl, valid, *replicated)         # async dispatch
             # (deterministic jobs: the executable itself tree-reduced
             # the rows, so `out` is already the chunk partial)
             if injector is not None:
@@ -1531,7 +1591,9 @@ class ElasticDispatcher:
                 # synchronous baseline (``streamed_sync``): materialize
                 # the chunk on host NOW — one blocking D2H per chunk,
                 # exactly the pre-async behavior this pipeline replaces
-                out = jax.tree_util.tree_map(np.asarray, out)
+                with span("dispatch.retire", collector, stream=sid,
+                          chunk=ci):
+                    out = jax.tree_util.tree_map(np.asarray, out)
                 if collector is not None:
                     collector.retire(ci, tainted=compiled_now)
                     if not guarded:
@@ -1652,8 +1714,9 @@ class ElasticDispatcher:
         combine_on_device = (deliver == "device" and depth > 0
                              and len(part_epochs) <= 1 and _resume is None)
         resume_base = None if _resume is None else _resume["base_state"]
-        outputs = self._combine(job, parts[base_k:], combine_on_device,
-                                base=resume_base)
+        with span("dispatch.combine", collector, stream=sid):
+            outputs = self._combine(job, parts[base_k:], combine_on_device,
+                                    base=resume_base)
         if journal is not None:
             # completion is durable too: journal any straggler chunks and
             # tail scale events, persist the combined output as the FINAL
@@ -1679,8 +1742,6 @@ class ElasticDispatcher:
         report.cache_hits = self.cache.hits - hits0
         report.scale_events = len(self.scale_events) - events0
         report.wall_s = time.perf_counter() - t_start
-        if collector is not None:
-            report.stats = collector.summary(n_servers=1)
         return outputs, report
 
     # ---------------------------------------------------- staging + combine
@@ -1820,7 +1881,8 @@ class ElasticDispatcher:
             return out
 
         if not job.deterministic:
-            return jax.jit(call, donate_argnums=self._CHUNK_DONATE)
+            return jax.jit(_named(call, job),
+                           donate_argnums=self._CHUNK_DONATE)
 
         # deterministic: the row tree compiles as its OWN executable so the
         # member_fn's producer can never FMA-contract into the level-0 adds
@@ -1832,9 +1894,9 @@ class ElasticDispatcher:
                 body, (chunk_tree, valid), replicated_args=rep,
                 out_specs=out_specs)
 
-        rows_fn = jax.jit(rows_call, donate_argnums=self._CHUNK_DONATE)
-        tree_fn = jax.jit(lambda out, valid: jax.tree_util.tree_map(
-            lambda a: _row_tree_sum(a, valid), out))
+        rows_fn = jax.jit(_named(rows_call, job, "rows"),
+                          donate_argnums=self._CHUNK_DONATE)
+        tree_fn = _row_tree_jit(job)
 
         def split_call(chunk_tree, valid, *rep):
             return tree_fn(rows_fn(chunk_tree, valid, *rep), valid)
@@ -1848,13 +1910,12 @@ class ElasticDispatcher:
         def run(chunk_tree, valid, *rep):
             return job.global_fn(chunk_tree, valid, *rep)
 
-        jitted = jax.jit(run, donate_argnums=self._CHUNK_DONATE)
+        jitted = jax.jit(_named(run, job), donate_argnums=self._CHUNK_DONATE)
         # deterministic: the row tree compiles as its OWN executable (a
         # nested jit would inline into the outer trace) so the global_fn's
         # producer can never FMA-contract into the level-0 adds — the same
         # fence as _build_member (see _row_tree_sum)
-        tree_fn = jax.jit(lambda out, valid: jax.tree_util.tree_map(
-            lambda a: _row_tree_sum(a, valid), out))
+        tree_fn = _row_tree_jit(job)
 
         def call(chunk_tree, valid, *rep):
             # auto-SPMD: place the chunk partitioned, the rest replicated,

@@ -102,7 +102,7 @@ class MapReduceEngine:
     """
 
     def __init__(self, mesh: Optional[Mesh] = None, backend: str = "hazelcast",
-                 axis: str = "data", verbose: bool = False,
+                 axis: str = "data",
                  dispatcher: Optional[ElasticDispatcher] = None):
         assert backend in ("hazelcast", "infinispan")
         if dispatcher is None:
@@ -113,7 +113,6 @@ class MapReduceEngine:
         self.dispatcher = dispatcher
         self.backend = backend
         self.axis = dispatcher.axis
-        self.verbose = verbose
         self.last_report = None          # DispatchReport of the latest run
 
     @property
@@ -155,7 +154,7 @@ class MapReduceEngine:
         return jnp.asarray(out)
 
     def _dispatch_job(self, job: MapReduceJob) -> DispatchJob:
-        return dispatch_job_for(job, self.backend, verbose=self.verbose)
+        return dispatch_job_for(job, self.backend)
 
     def benchmark(self, job: MapReduceJob, files, repeats: int = 3, *,
                   chunk: Optional[int] = None):
@@ -169,8 +168,8 @@ class MapReduceEngine:
         return out, (time.perf_counter() - t0) / repeats
 
 
-def dispatch_job_for(job: MapReduceJob, backend: str = "hazelcast",
-                     verbose: bool = False) -> DispatchJob:
+def dispatch_job_for(job: MapReduceJob,
+                     backend: str = "hazelcast") -> DispatchJob:
     """The MapReduce job as a dispatch descriptor — module-level so engine-
     LESS callers (``serve.frontend.mapreduce_request``) can build dispatch
     jobs too.  ``map_fn`` itself is part of the signature: a fresh closure
@@ -200,9 +199,6 @@ def dispatch_job_for(job: MapReduceJob, backend: str = "hazelcast",
         # explicit member-local map + collective reduce (psum)
         def member_fn(local_files, valid, *_):
             counts = jax.vmap(job.map_fn)(local_files)   # one per file
-            if verbose:
-                jax.debug.print("[member] mapped {} files locally",
-                                local_files.shape[0])
             counts = jnp.where(valid[:, None], counts, 0)
             return counts.sum(axis=0)
 

@@ -275,6 +275,9 @@ class ChunkTimeline:
 class DispatchStats:
     """The per-stream stage-stamp collector.
 
+    ``record_span`` keeps the dispatcher's stage spans (``core.spans``)
+    beside the stamps: count, total and self seconds per span name.
+
     ``serialized=True`` (the dispatcher's pipeline) measures SERVICE as the
     exclusive device interval ``retire_i - max(dispatch_i, retire_{i-1})``:
     under pipelining a chunk's launch-to-retire wall includes time queued
@@ -311,6 +314,7 @@ class DispatchStats:
         self.checkpoint_s: List[float] = []
         self.rejections: Dict[str, int] = {}   # reason -> count (admission/
         #                                        shedding/serve-layer drops)
+        self.spans: Dict[str, List[float]] = {}  # name -> [n, total, self]
         self._open: Dict[int, ChunkTimeline] = {}    # enqueued, not launched
         self._live: Dict[int, ChunkTimeline] = {}    # launched, not validated
         self._last_retire: Optional[float] = None
@@ -380,6 +384,14 @@ class DispatchStats:
         BENCH_resume.json's overhead entries)."""
         self.checkpoint_s.append(float(write_s))
         self.hist.record("checkpoint", write_s)
+
+    def record_span(self, name: str, total_s: float, self_s: float) -> None:
+        """One closed ``core.spans.span``: its wall time and the part of it
+        not covered by the spans nested in it."""
+        rec = self.spans.setdefault(name, [0, 0.0, 0.0])
+        rec[0] += 1
+        rec[1] += total_s
+        rec[2] += self_s
 
     # ------------------------------------------------------------ intervals
     def _close(self, rec: ChunkTimeline) -> None:
@@ -479,6 +491,9 @@ class DispatchStats:
             out["rejections"] = {k: float(v)
                                  for k, v in sorted(self.rejections.items())}
             out["n_rejected"] = float(sum(self.rejections.values()))
+        if self.spans:
+            out["spans"] = {k: {"n": float(n), "total_s": t, "self_s": s}
+                            for k, (n, t, s) in sorted(self.spans.items())}
         out["queue"] = self.queue_summary(n_servers)
         return out
 
