@@ -416,6 +416,9 @@ class DispatchJob:
     # provenance: None (lax), "compiled" (real Pallas kernel), or
     # "interpret" (off-TPU fallback) — see compat.kernel_path
     kernel_path: Optional[str] = None
+    # which path a MapReduce job's map counts with, by platform ("default"
+    # for every other): resolved into DispatchReport.map_path
+    map_paths: Optional[Dict[str, str]] = None
 
     def __post_init__(self):
         if (self.member_fn is None) == (self.global_fn is None):
@@ -447,6 +450,9 @@ class DispatchReport:
     # the off-TPU fallback — so a CPU "kernel" benchmark can't silently
     # report interpreter timings as kernel timings
     kernel_path: Optional[str] = None
+    # the path the job's map counted with on these devices (from
+    # DispatchJob.map_paths): e.g. "mxu_onehot", "scatter", "kernel"
+    map_path: Optional[str] = None
     ema_step_s: float = 0.0              # last step-time EMA (auto_scale)
     retries: int = 0                     # chunk replays this stream
     # structured failure record: one dict per DETECTED failure —
@@ -771,6 +777,13 @@ class ElasticDispatcher:
             self.job_targets[job.signature] = target
         return target
 
+    def _map_path(self, job: DispatchJob) -> Optional[str]:
+        """``job.map_paths`` resolved for the platform of this pool."""
+        if job.map_paths is None:
+            return None
+        platform = self.devices[0].platform
+        return job.map_paths.get(platform, job.map_paths.get("default"))
+
     # ---------------------------------------------------- durable dispatch
     def request_drain(self) -> None:
         """Ask the active JOURNALED stream to preempt gracefully: at the
@@ -905,7 +918,8 @@ class ElasticDispatcher:
                 job=job.name, n_items=B, chunk=chunk_, n_chunks=n_chunks,
                 journal_path=path, resumed_from=path,
                 chunks_skipped=n_chunks, chunks_replayed=0,
-                kernel_path=job.kernel_path)
+                kernel_path=job.kernel_path,
+                map_path=self._map_path(job))
             return outputs, report
 
         snap = state.last_snapshot
@@ -1127,7 +1141,8 @@ class ElasticDispatcher:
 
         report = DispatchReport(job=job.name, n_items=B, chunk=chunk,
                                 n_chunks=n_chunks, dispatch_ahead=depth,
-                                kernel_path=job.kernel_path)
+                                kernel_path=job.kernel_path,
+                                map_path=self._map_path(job))
         hits0, builds0 = self.cache.hits, self.cache.builds
         events0 = len(self.scale_events)
         # durability: open (or adopt, on resume) the stream's journal and

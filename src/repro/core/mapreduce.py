@@ -29,10 +29,31 @@ Jobs follow the paper's default example: word count over a corpus of files.
 ``map_invocations`` = number of files (leading shard dim); ``reduce
 invocations`` = number of distinct keys touched (vocab bins), matching how the
 thesis scales its experiments (§4.2.3).
+
+How word count's map turns one file's ids into counts depends on where it
+runs, and is chosen when the program is lowered
+(``jax.lax.platform_dependent``), never by a flag:
+
+  TPU, vocab <= ONEHOT_MAX_VOCAB   ``onehot_counts``: a two-level one-hot
+                        contraction on the MXU.  The padded vocabulary is
+                        factored as H x L; each id gives one-hots of its
+                        high and low part, and ``onehot_hiᵀ · onehot_lo`` is
+                        the [H, L] count table.  A scatter-add whose indices
+                        collide is applied one update after another on the
+                        TPU; the contraction costs H + L compares a token on
+                        the VPU and moves the sum over tokens to the MXU.
+  anywhere else         ``scatter_counts``: ``.at[ids].add(1)``, which the
+                        CPU runs fast and whose per-token cost does not grow
+                        with the vocabulary.
+
+Both give the same int32 counts bit for bit: the contraction's operands are
+0/1 int8 and it accumulates in int32, so every count is exact.  Which path
+counted a stream is ``DispatchReport.map_path``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional
 
@@ -42,6 +63,11 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core.dispatch import DispatchJob, ElasticDispatcher
+
+# Largest vocabulary the one-hot contraction counts on the TPU.  Its VPU work
+# grows with H + L ~ 2·sqrt(vocab) a token and its MXU work with vocab, while
+# the serialised scatter costs about the same a token at any vocabulary.
+ONEHOT_MAX_VOCAB = 65536
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,23 +83,86 @@ class MapReduceJob:
     n_keys: int                     # size of the reduced key space
     name: str = "job"
     deterministic: bool = False     # fixed-tree float reduction
+    # which path map_fn counts with, by platform ("default": every other):
+    # resolved against the dispatcher's devices into DispatchReport.map_path
+    map_paths: Optional[dict] = None
+
+
+def _factor(vocab: int):
+    """(H, L, log2 L) with L a power of two and H·L >= vocab, H + L least."""
+    best = None
+    for k in range(max(vocab, 1).bit_length() + 1):
+        h = -(-vocab // (1 << k))
+        if best is None or h + (1 << k) < best[0] + best[1]:
+            best = (h, 1 << k, k)
+    return best
+
+
+def onehot_counts(flat: jax.Array, vocab: int) -> jax.Array:
+    """int32 counts of ``flat``'s ids over ``vocab`` bins as one int8
+    contraction: ``onehot(id >> k)ᵀ · onehot(id & (L - 1))`` is the [H, L]
+    table of the padded vocabulary, sliced to ``vocab``.
+
+    Exact for any ids: the operands are 0/1 int8 and the dot accumulates in
+    int32.  The index semantics are ``scatter_counts``'s: an id in
+    ``[-vocab, 0)`` counts at ``id + vocab`` (``.at[]`` wraps negative
+    indices), any other id outside ``[0, vocab)`` is dropped — its high part
+    is outside ``[0, H)`` or it lands in a padding bin the slice removes.
+
+    The parts are narrowed to int8 (int16 past 127 rows) before the
+    compares, the high part clipped to ``[-1, H]`` so nothing wraps into
+    range; narrowed, XLA keeps them out of HBM too.  The one-hots are
+    [H, n] and [L, n], lane-dense along the tokens: XLA fuses them into the
+    dot's operands and never writes them out."""
+    h, l, k = _factor(vocab)
+    dt = jnp.int8 if max(h, l) < 128 else jnp.int16
+    ids = jnp.where(flat < 0, flat + vocab, flat)
+    hi = jnp.clip(ids >> k, -1, h).astype(dt)
+    lo = (ids & (l - 1)).astype(dt)
+    onehot_hi = (hi == jnp.arange(h, dtype=dt)[:, None]).astype(jnp.int8)
+    onehot_lo = (lo == jnp.arange(l, dtype=dt)[:, None]).astype(jnp.int8)
+    counts = jax.lax.dot_general(onehot_hi, onehot_lo,
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.int32)
+    return counts.reshape(-1)[:vocab]
+
+
+def scatter_counts(flat: jax.Array, vocab: int) -> jax.Array:
+    """int32 counts of ``flat``'s ids over ``vocab`` bins by a scatter-add
+    (negative ids wrap once, the rest out of range are dropped)."""
+    return jnp.zeros((vocab,), jnp.int32).at[flat].add(
+        jnp.ones_like(flat), mode="drop")
 
 
 def word_count_job(vocab: int, use_kernel: bool = False) -> MapReduceJob:
     """The paper's default word-count application: counts token occurrences.
 
+    ``map_fn`` counts one file.  On the TPU, for ``vocab <=
+    ONEHOT_MAX_VOCAB``, it is ``onehot_counts`` (the MXU contraction); on
+    any other platform, or above that vocabulary, ``scatter_counts``.  The
+    branch is taken when the program is lowered for its platform.  Both are
+    exact int32 counts with the same index semantics, so the result is
+    bit-identical whichever path runs.
+
     use_kernel: route the per-shard histogram through the Pallas histogram
-    kernel (interpret mode on CPU) instead of the jnp one-hot path.
+    kernel (interpret mode on CPU) instead.
     """
     if use_kernel:
         from repro.kernels.histogram import ops as hist_ops
         fn = lambda chunk: hist_ops.histogram(chunk.reshape(-1), vocab)
-    else:
+        paths = {"default": "kernel"}
+    elif vocab <= ONEHOT_MAX_VOCAB:
         def fn(chunk):
-            flat = chunk.reshape(-1)
-            return jnp.zeros((vocab,), jnp.int32).at[flat].add(
-                jnp.ones_like(flat), mode="drop")
-    return MapReduceJob(map_fn=fn, n_keys=vocab, name="word_count")
+            return jax.lax.platform_dependent(
+                chunk.reshape(-1),
+                tpu=functools.partial(onehot_counts, vocab=vocab),
+                default=functools.partial(scatter_counts, vocab=vocab))
+        paths = {"tpu": "mxu_onehot", "default": "scatter"}
+    else:
+        fn = lambda chunk: scatter_counts(chunk.reshape(-1), vocab)
+        paths = {"default": "scatter"}
+    return MapReduceJob(map_fn=fn, n_keys=vocab, name="word_count",
+                        map_paths=paths)
 
 
 def word_weight_job(vocab: int) -> MapReduceJob:
@@ -89,7 +178,7 @@ def word_weight_job(vocab: int) -> MapReduceJob:
         return jnp.zeros((vocab,), jnp.float32).at[flat].add(w, mode="drop")
 
     return MapReduceJob(map_fn=fn, n_keys=vocab, name="word_weight",
-                        deterministic=True)
+                        deterministic=True, map_paths={"default": "scatter"})
 
 
 class MapReduceEngine:
@@ -193,6 +282,7 @@ def dispatch_job_for(job: MapReduceJob,
         kw = ({"member_fn": per_row} if backend == "hazelcast"
               else {"global_fn": per_row})
         return DispatchJob(name=f"mapreduce/{job.name}", signature=sig,
+                           map_paths=job.map_paths,
                            reduce="sum", deterministic=True, **kw)
 
     if backend == "hazelcast":
@@ -203,6 +293,7 @@ def dispatch_job_for(job: MapReduceJob,
             return counts.sum(axis=0)
 
         return DispatchJob(name=f"mapreduce/{job.name}", signature=sig,
+                           map_paths=job.map_paths,
                            member_fn=member_fn, reduce="sum")
 
     # infinispan: one global expression, auto-SPMD partitioning
@@ -211,6 +302,7 @@ def dispatch_job_for(job: MapReduceJob,
         return jnp.where(valid[:, None], counts, 0).sum(axis=0)
 
     return DispatchJob(name=f"mapreduce/{job.name}", signature=sig,
+                       map_paths=job.map_paths,
                        global_fn=global_fn, reduce="sum")
 
 

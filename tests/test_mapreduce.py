@@ -97,3 +97,75 @@ assert eng.last_report.n_chunks == 4
 print("OK")
 """], env=env, capture_output=True, text=True, timeout=900)
     assert "OK" in r.stdout, r.stdout + r.stderr
+
+
+def _bincount_like_scatter(files, valid, vocab):
+    """np.bincount with ``.at[ids].add(mode="drop")``'s index semantics: an
+    id in [-vocab, 0) counts at id + vocab, any other id outside
+    [0, vocab) is dropped; files with ``valid`` False count nothing."""
+    ids = np.asarray(files, np.int64)[np.asarray(valid)].reshape(-1)
+    ids = np.where(ids < 0, ids + vocab, ids)
+    return np.bincount(ids[(ids >= 0) & (ids < vocab)],
+                       minlength=vocab).astype(np.int32)
+
+
+def _ids(case, vocab, n_files, n, rng):
+    if case == "in_range":
+        return rng.integers(0, vocab, (n_files, n))
+    if case == "wrapped_negative":
+        return rng.integers(-vocab, 0, (n_files, n))
+    if case == "out_of_range":
+        ids = rng.integers(-vocab - 70, vocab + 70, (n_files, n))
+        ids[0, :4] = [np.iinfo(np.int32).min, np.iinfo(np.int32).max,
+                      -vocab - 1, vocab]
+        return ids
+    assert case == "one_repeated_id"
+    return np.full((n_files, n), vocab // 2)
+
+
+@pytest.mark.parametrize("case", ["in_range", "wrapped_negative",
+                                  "out_of_range", "one_repeated_id"])
+@pytest.mark.parametrize("vocab", [1, 8, 48, 300, 1000, 4096, 20000])
+def test_onehot_contraction_matches_bincount_and_scatter(vocab, case):
+    """The TPU's map (``onehot_counts``: int8 one-hots of each id's high and
+    low part, contracted with int32 accumulation) counts exactly what the
+    scatter-add counts, bit for bit, vmapped over files and masked as the
+    dispatch job masks them.  300 and 48 factor into non-square H x L, and
+    20000 (157 x 128) takes int16 parts.  The
+    last file is padding (``valid`` False) and must not count.  The CPU
+    engine lowers the scatter, so this is what guards the TPU branch."""
+    from repro.core.mapreduce import onehot_counts, scatter_counts
+
+    rng = np.random.default_rng(vocab)
+    files = _ids(case, vocab, 5, 1000, rng).astype(np.int32)
+    valid = np.array([True] * 4 + [False])
+
+    def masked_sum(fn):
+        counts = jax.vmap(lambda f: fn(f, vocab))(jnp.asarray(files))
+        return np.asarray(jnp.where(jnp.asarray(valid)[:, None], counts,
+                                    0).sum(axis=0))
+
+    got = masked_sum(onehot_counts)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, _bincount_like_scatter(files, valid,
+                                                              vocab))
+    np.testing.assert_array_equal(got, masked_sum(scatter_counts))
+
+
+def test_word_count_reports_its_map_path():
+    """The dispatch summary names the path that counted: the scatter on the
+    CPU, the Pallas kernel with ``use_kernel``; the word-count job picks the
+    one-hot contraction on the TPU up to ``ONEHOT_MAX_VOCAB``."""
+    from repro.core.mapreduce import ONEHOT_MAX_VOCAB, word_weight_job
+
+    corpus = jnp.asarray(make_corpus(4, 256, vocab=16, seed=2))
+    eng = MapReduceEngine(mesh1(), backend="hazelcast")
+    for job, path in ((word_count_job(16), "scatter"),
+                      (word_count_job(16, use_kernel=True), "kernel"),
+                      (word_weight_job(16), "scatter")):
+        eng.run(job, corpus)
+        assert eng.last_report.summary()["map_path"] == path
+    assert word_count_job(16).map_paths == {"tpu": "mxu_onehot",
+                                            "default": "scatter"}
+    assert word_count_job(ONEHOT_MAX_VOCAB + 1).map_paths == {
+        "default": "scatter"}

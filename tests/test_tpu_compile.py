@@ -115,3 +115,24 @@ def test_exchange_core_compiles_on_four_chips(topo):
                    _shape(rep, (V,), jnp.float32),
                    _shape(part, (n,), jnp.bool_)).compile().as_text()
     assert "all-to-all" in hlo
+
+
+@pytest.mark.parametrize("vocab, path", [(1000, "convolution"),
+                                         (65537, "scatter")])
+def test_word_count_map_lowers_per_vocab(one_chip, vocab, path):
+    """Lowered for the chip, word count's map is the int8 one-hot
+    contraction (a convolution on the MXU, no scatter) up to
+    ``ONEHOT_MAX_VOCAB``, and the scatter-add above it; vmapped over a
+    chunk's files and masked as the dispatch job does."""
+    from repro.core.mapreduce import word_count_job
+
+    job = word_count_job(vocab)
+
+    def counts(files, valid):
+        c = jax.vmap(job.map_fn)(files)
+        return jnp.where(valid[:, None], c, 0).sum(axis=0)
+
+    hlo = _compile(counts, _shape(one_chip, (8, 4096), jnp.int32),
+                   _shape(one_chip, (8,), jnp.bool_))
+    other = {"convolution": "scatter", "scatter": "convolution"}[path]
+    assert path in hlo and other not in hlo
