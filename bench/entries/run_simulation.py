@@ -41,15 +41,33 @@ def _broker(name: str, mi, mips, rule: dict, dtype):
     return stand_in, want, also
 
 
-def _mismatches(got, want, also, mi, mips, where: str) -> int:
+def _requirement(mi, mips, rule: dict):
+    """A cloudlet's least adequate MIPS under the matchmaking rule, in
+    float64 and in float32 arithmetic, and the VM whose MIPS lies nearest
+    the float64 one with its relative distance: a distance within float32
+    rounding tells a boundary case from a fault of the broker's logic."""
+    top = np.max(mips)
+    f64 = float(mi) / rule["max_mi"] * (rule["headroom"] * float(top))
+    f32 = (np.float32(mi) / np.float32(rule["max_mi"])
+           * (np.float32(top) * np.float32(rule["headroom"])))
+    dist = np.abs(np.asarray(mips, np.float64) - f64) / f64
+    near = int(np.argmin(dist))
+    return f64, f32, near, float(dist[near])
+
+
+def _mismatches(got, want, also, mi, mips, rule: dict, where: str) -> int:
     """``matchmaking.mismatches`` counted, the first few named on standard
-    error."""
+    error with the cloudlet's requirement and its distance to the nearest
+    VM's MIPS."""
     got = np.asarray(got)
     bad = matchmaking.mismatches(got, want, also)
     for b in bad[:3]:
+        f64, f32, near, dist = _requirement(mi[b], mips, rule)
         print(f"broker mismatch {where} cloudlet {b} mi {mi[b]!r} got VM "
               f"{got[b]} ({mips[got[b]]!r} MIPS) want VM {want[b]} "
-              f"({mips[want[b]]!r} MIPS)", file=sys.stderr)
+              f"({mips[want[b]]!r} MIPS); requires {f64!r} MIPS (float64), "
+              f"{f32!r} (float32); nearest VM {near} ({mips[near]!r} MIPS) "
+              f"at relative distance {dist!r}", file=sys.stderr)
     return int(bad.size)
 
 
@@ -95,7 +113,8 @@ def compare(samples, sim: dict, rng: np.random.Generator,
         stand_in, want, also = _broker(s["broker"], mi, mips,
                                        sim["matchmaking"], dtype)
         mism += _mismatches(assign if stand_in is None else stand_in, want,
-                            also, mi, mips, f"seed {s['seed']}")
+                            also, mi, mips, sim["matchmaking"],
+                            f"seed {s['seed']}")
         if dtype is np.float64:
             ref = timeshared.finish_times_all(assign, mi, mips)
             worst = max(worst, _rel(finish, ref),

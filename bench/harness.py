@@ -95,6 +95,9 @@ class Context:
     records: list                  # one dict per completed request
     trace: Optional[object]        # trace.Reduction of a traced run
     device_kind: str
+    # JAX's traces, XLA compiles and persistent-cache loads in the window
+    # (``CompileCounter.counts``, the ``window`` line)
+    window_programs: dict = dataclasses.field(default_factory=dict)
 
     def rate(self, unit: str) -> Optional[float]:
         """All ``unit`` work completed in the window over the time from the
@@ -245,7 +248,8 @@ def run(root: str, spec: dict, workload: str, seed: int, seconds: float,
 
     ctx = Context(cell=cell, config=config, traffic=traffic, setup_s=setup_s,
                   window_start=t0, records=records, trace=reduction,
-                  device_kind=devices[0].device_kind)
+                  device_kind=devices[0].device_kind,
+                  window_programs=dict(counter.counts))
     metrics = {}
     for m in wanted:
         v = readers[m["name"]].read(ctx)
