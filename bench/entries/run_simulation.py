@@ -24,21 +24,23 @@ import generator
 from reference import entities, matchmaking, timeshared
 
 
-def _broker(name: str, mi, mips, rule: dict, dtype):
-    """(stand-in, expected, also-accepted) VM per cloudlet.  The stand-in is
-    the reference in ``dtype`` when that is below float64 (the control), and
-    None otherwise, so that the program's own answer is compared."""
+def _broker(name: str, got, mi, mips, rule: dict, dtype):
+    """(expected VM, compared VM, ids of the cloudlets whose compared VM the
+    reference does not accept) per cloudlet.  The compared VM is the
+    program's ``got``, or, below float64, the reference in ``dtype`` (the
+    control)."""
     if name != "matchmaking":
         want = matchmaking.round_robin(mi.size, mips.size)
-        return (want if dtype is not np.float64 else None), want, want
-    want, also = matchmaking.matchmaking(mi, mips, max_mi=rule["max_mi"],
-                                         headroom=rule["headroom"])
-    stand_in = None
+        got = np.asarray(got) if dtype is np.float64 else want
+        return want, got, np.nonzero(got != want)[0]
+    want, order, lo, hi = matchmaking.matchmaking(
+        mi, mips, max_mi=rule["max_mi"], headroom=rule["headroom"])
     if dtype is not np.float64:
-        stand_in, _ = matchmaking.matchmaking(
+        got = matchmaking.matchmaking(
             mi, mips, max_mi=rule["max_mi"], headroom=rule["headroom"],
-            dtype=dtype, band=0.0)
-    return stand_in, want, also
+            dtype=dtype, band=0.0)[0]
+    got = np.asarray(got)
+    return want, got, matchmaking.mismatches(got, order, lo, hi)
 
 
 def _requirement(mi, mips, rule: dict):
@@ -55,12 +57,10 @@ def _requirement(mi, mips, rule: dict):
     return f64, f32, near, float(dist[near])
 
 
-def _mismatches(got, want, also, mi, mips, rule: dict, where: str) -> int:
-    """``matchmaking.mismatches`` counted, the first few named on standard
-    error with the cloudlet's requirement and its distance to the nearest
-    VM's MIPS."""
-    got = np.asarray(got)
-    bad = matchmaking.mismatches(got, want, also)
+def _mismatches(bad, got, want, mi, mips, rule: dict, where: str) -> int:
+    """The broker's mismatches ``bad`` counted, the first few named on
+    standard error with the cloudlet's requirement and its distance to the
+    nearest VM's MIPS."""
     for b in bad[:3]:
         f64, f32, near, dist = _requirement(mi[b], mips, rule)
         print(f"broker mismatch {where} cloudlet {b} mi {mi[b]!r} got VM "
@@ -110,10 +110,9 @@ def compare(samples, sim: dict, rng: np.random.Generator,
                                        sim["cloudlet_mi_range"])
         assign = np.asarray(s["assign"])
         finish = np.asarray(s["finish"])
-        stand_in, want, also = _broker(s["broker"], mi, mips,
-                                       sim["matchmaking"], dtype)
-        mism += _mismatches(assign if stand_in is None else stand_in, want,
-                            also, mi, mips, sim["matchmaking"],
+        want, got, bad = _broker(s["broker"], assign, mi, mips,
+                                 sim["matchmaking"], dtype)
+        mism += _mismatches(bad, got, want, mi, mips, sim["matchmaking"],
                             f"seed {s['seed']}")
         if dtype is np.float64:
             ref = timeshared.finish_times_all(assign, mi, mips)

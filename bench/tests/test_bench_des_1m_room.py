@@ -80,7 +80,7 @@ def hand_context():
         cell={}, config={}, traffic={"n_cloudlets": 1024, "n_vms": 16},
         setup_s=1.0, window_start=0.0, records=records,
         trace=tr.reduce(trace), device_kind="TPU v5 lite",
-        window_programs={"traces": 130, "compiles": 0, "cache_loads": 92})
+        window_programs={"traces": 130, "compiles": 92, "cache_loads": 92})
 
 
 def test_simulation_readers_on_a_hand_built_context():

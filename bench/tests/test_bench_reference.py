@@ -50,11 +50,79 @@ def simulation():
 
 def test_program_broker_equals_the_reference_and_bf16_does_not(simulation):
     res, mips, mi = simulation
-    want, also = matchmaking.matchmaking(mi, mips, **RULE)
-    assert matchmaking.mismatches(res.vm_assign, want, also).size == 0
-    low, _ = matchmaking.matchmaking(mi, mips, **RULE,
-                                     dtype=ml_dtypes.bfloat16, band=0.0)
-    assert matchmaking.mismatches(low, want, also).size > 0
+    _, order, lo, hi = matchmaking.matchmaking(mi, mips, **RULE)
+    assert matchmaking.mismatches(res.vm_assign, order, lo, hi).size == 0
+    low = matchmaking.matchmaking(mi, mips, **RULE,
+                                  dtype=ml_dtypes.bfloat16, band=0.0)[0]
+    assert matchmaking.mismatches(low, order, lo, hi).size > 0
+
+
+# a rule whose requirement is the cloudlet's length itself, exactly
+UNIT_RULE = {"max_mi": 2000.0, "headroom": 1.0}
+
+
+def accepted(mips, n_cloudlets, **band):
+    """The expected VM of cloudlets 0..n-1 of 1000 MI each, and the set of
+    VMs the reference accepts for each of them (by default in the band that
+    the check applies)."""
+    mi = np.full(n_cloudlets, 1000.0)
+    want, order, lo, hi = matchmaking.matchmaking(mi, mips, **UNIT_RULE,
+                                                  **band)
+    sets = [set() for _ in range(n_cloudlets)]
+    for v in range(len(mips)):
+        bad = set(matchmaking.mismatches(np.full(n_cloudlets, v), order,
+                                         lo, hi).tolist())
+        for c in range(n_cloudlets):
+            if c not in bad:
+                sets[c].add(v)
+    return want, sets
+
+
+# sorted by MIPS: VMs 2, 6, 5, 3, 1, 4, 0; VMs 3 and 1 lie inside the band
+# of the 1000-MIPS requirement, VM 5 just below it
+TWO_IN_BAND = np.array([2000.0, 1000.0 * (1 + 5e-7), 500.0,
+                        1000.0 * (1 - 5e-7), 1500.0, 1000.0 * (1 - 2e-6),
+                        700.0])
+
+
+def test_two_vms_inside_the_band_accept_the_pick_of_each_first_position():
+    # the first adequate position may be 3, 4 or 5 (VM 3, 1 or 4); cloudlet
+    # c binds to the VM at position f + c mod (7 - f)
+    want, sets = accepted(TWO_IN_BAND, 3)
+    assert want.tolist() == [1, 4, 0]
+    assert sets == [{3, 1, 4}, {1, 4, 0}, {4, 0}]
+
+
+def test_a_vm_just_outside_the_band_is_refused():
+    # VM 5 lies 2e-6 below the requirement: first for no requirement inside
+    # a band of 1e-6, first for one inside a band of 3e-6
+    _, sets = accepted(TWO_IN_BAND, 1)
+    assert 5 not in sets[0]
+    _, wider = accepted(TWO_IN_BAND, 1, band=3e-6)
+    assert 5 in wider[0]
+
+
+def test_with_no_vm_in_the_band_only_the_expected_vm_is_accepted():
+    mips = np.array([2000.0, 500.0, 1500.0, 1000.0 * (1 + 1e-5), 700.0])
+    want, sets = accepted(mips, 4)
+    assert want.tolist() == [3, 2, 0, 3]
+    assert sets == [{int(w)} for w in want]
+
+
+def test_the_float32_boundary_the_chip_met_is_accepted():
+    # simulation seed 1595435016: the TPU's float32 requirement of cloudlet
+    # 498425 equals VM 649's MIPS, one float32 step below the float64 one,
+    # and its broker picked VM 330; the first adequate position may be 618,
+    # 619 or 620 (VM 55, 330 or 443), and nothing else is accepted
+    mips, mi = entities.simulation(1595435016, 1024, 2 ** 20, MIPS, MI)
+    want, order, lo, hi = matchmaking.matchmaking(mi, mips, **RULE)
+    c = 498425
+    assert (want[c], lo[c], hi[c]) == (443, 618, 620)
+    got = want.copy()
+    for vm, fair in ((330, True), (55, True), (443, True), (649, False),
+                     (331, False)):
+        got[c] = vm
+        assert (matchmaking.mismatches(got, order, lo, hi).size == 0) == fair
 
 
 def test_program_finish_times_equal_the_reference_and_bf16_do_not(simulation):
