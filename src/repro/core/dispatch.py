@@ -49,14 +49,15 @@ pairwise trees (rows) plus a fixed-arity tree keyed on chunk index (chunks).
 
 The streaming path is an ASYNC, DOUBLE-BUFFERED pipeline (``dispatch_ahead``
 launched-but-unretired chunks, default 2): chunk k+1 is staged on the host —
-or cut on DEVICE via ``executor.slice_chunk`` when the item set is already
-device-resident — while chunk k computes, and the host blocks only to bound
-the queue, to take the wall-time samples the IAS needs (an EMA of
-retirement-to-retirement step times over a per-job-class calibrated
-``target_step_time``), and at reduce/remesh boundaries.  A scale event is a
-pipeline BARRIER: drain in-flight chunks, rebalance, rebuild, resume — chunk
-boundaries and reduce order never change, so results stay bit-identical no
-matter how many chunks were in flight.
+or, when the item set is already device-resident, cut on DEVICE: in place by
+the job's own executable on a one-member mesh that holds the source, by
+``executor.slice_chunk`` otherwise — while chunk k computes, and the host
+blocks only to bound the queue, to take the wall-time samples the IAS needs
+(an EMA of retirement-to-retirement step times over a per-job-class
+calibrated ``target_step_time``), and at reduce/remesh boundaries.  A scale
+event is a pipeline BARRIER: drain in-flight chunks, rebalance, rebuild,
+resume — chunk boundaries and reduce order never change, so results stay
+bit-identical no matter how many chunks were in flight.
 
 FAILURE is a recoverable event at this layer, not a dead job (Hazelcast's
 defining property beyond elasticity is surviving member departure; see
@@ -94,7 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.executor import DistributedExecutor
+from repro.core.executor import DistributedExecutor, cut_chunk
 from repro.core.faults import (CompileFailedError, FaultInjector,
                                JobFailedError, MemberFailedError, RetryPolicy)
 from repro.core.grid import DataGrid
@@ -443,7 +444,8 @@ class DispatchReport:
     wall_s: float = 0.0
     dispatch_ahead: int = 0              # pipeline depth this stream ran at
     max_in_flight: int = 0               # peak launched-but-unretired chunks
-    staged_device: int = 0               # chunks cut on device (slice_chunk)
+    staged_device: int = 0               # chunks cut on device (either way)
+    staged_in_place: int = 0             # ... by the job's own executable
     staged_host: int = 0                 # chunks sliced/padded host-side
     # seg-scan kernel provenance (from DispatchJob.kernel_path): None for
     # the lax path, "compiled" for the real Pallas kernel, "interpret" for
@@ -555,9 +557,10 @@ class ElasticDispatcher:
         # unretired one (0 = fully synchronous, the pre-async baseline)
         self.dispatch_ahead = max(int(dispatch_ahead), 0)
         # device-resident item sets at least this big are chunked on device
-        # (``executor.slice_chunk``) instead of round-tripping through host
-        # numpy; below it the extra per-chunk jit dispatch costs more than
-        # the copies it saves (tests pin 0 to force the device path)
+        # (in place, or by ``executor.slice_chunk``) instead of round-
+        # tripping through host numpy; below it the per-chunk device cut
+        # costs more than the copies it saves (tests pin 0 to force the
+        # device path)
         self.device_slice_min_bytes = 1 << 20
         self.grid: Optional[DataGrid] = None
         self.scale_events: List[dict] = []
@@ -979,10 +982,16 @@ class ElasticDispatcher:
 
         Staging: a DEVICE-resident item set (every leaf a ``jax.Array``) of
         at least ``device_slice_min_bytes`` never round-trips to host — the
-        source is padded once on device and chunks are cut with
-        ``executor.slice_chunk`` (``lax.dynamic_slice`` + valid masking);
-        host-resident (or tiny, where an extra per-chunk jit dispatch costs
-        more than the copies it saves) items use numpy slicing as before.
+        source is padded once on device and each chunk's window is cut on
+        device (``lax.dynamic_slice`` + valid masking).  On a one-member
+        mesh whose device holds the source, the job's executable takes the
+        whole source with the chunk's ``lo``/``n_live`` and cuts its window
+        itself, so the window is read in place and no chunk buffer is
+        written (``staged_in_place``; a deterministic job's rows stage
+        excepted); where passing the source would move it (several members,
+        another device) ``executor.slice_chunk`` copies the chunk out first.
+        Host-resident (or tiny, where the per-chunk device cut costs more
+        than the copies it saves) items use numpy slicing as before.
         When no scale event fired mid-stream, outputs stay on device and are
         exposed LAZILY (callers chain them into the next job or block at
         their own reduce boundary); a remesh mixes geometries, so the final
@@ -1575,19 +1584,25 @@ class ElasticDispatcher:
                                    cause="member crash detected at launch")
                     return False
             with span("dispatch.stage", collector, stream=sid, chunk=ci):
-                if on_device:
-                    sl, valid = self.executor.slice_chunk(src, lo, L, n_live)
-                    report.staged_device += 1
+                in_place = on_device and self._reads_in_place(job, src)
+                if in_place:
+                    # the executable cuts rows [lo, lo + L) itself
+                    args = (src, lo, n_live)
+                    report.staged_in_place += 1
+                elif on_device:
+                    args = self.executor.slice_chunk(src, lo, L, n_live)
                 else:
-                    sl, valid = self._stage_host(items_np, lo, n_live, L)
+                    args = self._stage_host(items_np, lo, n_live, L)
                     report.staged_host += 1
+                report.staged_device += on_device
             with span("dispatch.launch", collector, stream=sid,
                       chunk=ci) as launching:
                 builds_before = self.cache.builds
                 try:
                     if injector is not None:
                         injector.on_compile(ci)
-                    fn = self._executable(job, sl, replicated, L)
+                    fn = self._executable(job, args[0], replicated, L,
+                                          in_place)
                 except CompileFailedError as e:
                     fail_chunk(ci, "compile_fail", detail=str(e))
                     return False
@@ -1597,7 +1612,7 @@ class ElasticDispatcher:
                 launch_epoch[ci] = self._epoch
                 if collector is not None:
                     collector.dispatch(ci, t_launch, tainted=compiled_now)
-                out = fn(sl, valid, *replicated)         # async dispatch
+                out = fn(*args, *replicated)             # async dispatch
             # (deterministic jobs: the executable itself tree-reduced
             # the rows, so `out` is already the chunk partial)
             if injector is not None:
@@ -1763,7 +1778,7 @@ class ElasticDispatcher:
     def _pad_device_source(self, items, chunk: int, n_chunks: int, B: int):
         """Pad a device-resident item source ONCE (repeating the last row —
         the same well-defined dead-row fill the host path uses) so every
-        fixed-shape ``slice_chunk`` window stays in bounds at ANY member
+        fixed-shape chunk window stays in bounds at ANY member
         count the IAS can reach.  ``pad_to_shards(chunk, m)`` is NOT
         monotone in m (pad_to_shards(4, 3) = 6 > pad_to_shards(4, 4) = 4),
         so the bound is the max over every possible member count — an
@@ -1836,25 +1851,45 @@ class ElasticDispatcher:
                                   pending=pending)
 
     # ------------------------------------------------------------ executables
-    def _executable(self, job: DispatchJob, chunk_tree, replicated, L: int):
+    def _reads_in_place(self, job: DispatchJob, src) -> bool:
+        """Whether the job's executable can take the whole device-resident
+        ``src`` and cut each chunk's window itself: the mesh is one member
+        and every leaf already lives on that member's device alone.  On a
+        wider mesh, or from another device, passing the source would move
+        all of it, so the chunk is copied out instead.  A deterministic
+        job's rows stage keeps the copy: its row tree needs the mask as an
+        operand of a second executable."""
+        if job.deterministic or self.executor.n_members != 1:
+            return False
+        here = {self.executor.device_list[0]}
+        return all(a.sharding.device_set == here
+                   for a in jax.tree_util.tree_leaves(src))
+
+    def _executable(self, job: DispatchJob, chunk_tree, replicated, L: int,
+                    in_place: bool = False):
         """One compiled callable per (mesh, axis, signature, reduce, shapes).
         The mesh in the key is the ONLY geometry binding: a scale event
         retires exactly the outgoing mesh's entries (``_remesh``), every
-        other geometry's executables stay warm for when the IAS returns."""
+        other geometry's executables stay warm for when the IAS returns.
+
+        ``in_place``: ``chunk_tree`` is the whole padded source and the
+        callable is ``fn(src, lo, n_live, *rep)``; it is compiled against
+        the source's shape, so its rows are part of the key."""
+        leaves = jax.tree_util.tree_leaves(chunk_tree)
         struct = tuple(
-            (tuple(a.shape[1:]), np.dtype(a.dtype).str)
-            for a in jax.tree_util.tree_leaves(chunk_tree))
+            (tuple(a.shape[1:]), np.dtype(a.dtype).str) for a in leaves)
         rep_struct = tuple(
             (tuple(np.shape(a)), np.dtype(np.asarray(a).dtype).str)
             for a in jax.tree_util.tree_leaves(replicated))
         mode = "member" if job.member_fn is not None else "global"
+        src_rows = int(leaves[0].shape[0]) if in_place else None
         key = (self.mesh, self.axis, job.signature, job.reduce,
-               job.deterministic, mode, L, struct, rep_struct)
+               job.deterministic, mode, L, struct, rep_struct, src_rows)
         fn = self.cache.get(key)
         if fn is None:
             builder = (self._build_member if mode == "member"
                        else self._build_global)
-            fn = builder(job)
+            fn = builder(job, L if in_place else None)
             self.cache.put(key, fn)
         return fn
 
@@ -1862,10 +1897,28 @@ class ElasticDispatcher:
     # staged afresh for every launch, replays included, and used exactly
     # once, so XLA can recycle its memory for outputs — steady-state
     # streaming then allocates nothing.  The valid mask is NOT donated: it
-    # is memoized across chunks (``_stage_host``).
+    # is memoized across chunks (``_stage_host``).  An in-place executable
+    # donates nothing: its argument 0 is the whole source, which every
+    # later chunk, replay and caller reads again.
     _CHUNK_DONATE = (0,)
 
-    def _build_member(self, job: DispatchJob):
+    def _jit_chunk(self, call: Callable, job: DispatchJob,
+                   cut: Optional[int], stage: str = ""):
+        """``jax.jit`` of ``call(chunk_tree, valid, *rep)`` named for the
+        job.  ``cut`` None: it takes the staged chunk, donated.  ``cut`` an
+        int L: it takes ``(src, lo, n_live, *rep)`` and cuts the L-row
+        window itself (``cut_chunk``), so XLA fuses the slice into the
+        window's first consumer and no chunk buffer is written."""
+        if cut is None:
+            return jax.jit(_named(call, job, stage),
+                           donate_argnums=self._CHUNK_DONATE)
+
+        def call_in_place(src, lo, n_live, *rep):
+            return call(*cut_chunk(src, lo, n_live, cut), *rep)
+
+        return jax.jit(_named(call_in_place, job, stage))
+
+    def _build_member(self, job: DispatchJob, cut: Optional[int] = None):
         executor = self.executor          # bound to the key's mesh
         axis = self.axis
         # a deterministic job's fn returns PER-ROW contributions which the
@@ -1896,8 +1949,7 @@ class ElasticDispatcher:
             return out
 
         if not job.deterministic:
-            return jax.jit(_named(call, job),
-                           donate_argnums=self._CHUNK_DONATE)
+            return self._jit_chunk(call, job, cut)
 
         # deterministic: the row tree compiles as its OWN executable so the
         # member_fn's producer can never FMA-contract into the level-0 adds
@@ -1909,8 +1961,7 @@ class ElasticDispatcher:
                 body, (chunk_tree, valid), replicated_args=rep,
                 out_specs=out_specs)
 
-        rows_fn = jax.jit(_named(rows_call, job, "rows"),
-                          donate_argnums=self._CHUNK_DONATE)
+        rows_fn = self._jit_chunk(rows_call, job, None, "rows")
         tree_fn = _row_tree_jit(job)
 
         def split_call(chunk_tree, valid, *rep):
@@ -1918,14 +1969,26 @@ class ElasticDispatcher:
 
         return split_call
 
-    def _build_global(self, job: DispatchJob):
+    def _build_global(self, job: DispatchJob, cut: Optional[int] = None):
         executor = self.executor
         axis = self.axis
 
         def run(chunk_tree, valid, *rep):
             return job.global_fn(chunk_tree, valid, *rep)
 
-        jitted = jax.jit(_named(run, job), donate_argnums=self._CHUNK_DONATE)
+        jitted = self._jit_chunk(run, job, cut)
+
+        def replicate(rep):
+            return tuple(jax.tree_util.tree_map(
+                lambda a: executor.put(jnp.asarray(a), P()), r) for r in rep)
+
+        if cut is not None:
+            # one member holds the source: the window is its own placement
+            def call_in_place(src, lo, n_live, *rep):
+                return jitted(src, lo, n_live, *replicate(rep))
+
+            return call_in_place
+
         # deterministic: the row tree compiles as its OWN executable (a
         # nested jit would inline into the outer trace) so the global_fn's
         # producer can never FMA-contract into the level-0 adds — the same
@@ -1938,10 +2001,7 @@ class ElasticDispatcher:
             sharded = jax.tree_util.tree_map(
                 lambda a: executor.put(jnp.asarray(a), P(axis)), chunk_tree)
             valid = executor.put(jnp.asarray(valid), P(axis))
-            rep = tuple(jax.tree_util.tree_map(
-                lambda a: executor.put(jnp.asarray(a), P()), r)
-                for r in rep)
-            out = jitted(sharded, valid, *rep)
+            out = jitted(sharded, valid, *replicate(rep))
             if job.deterministic:
                 out = tree_fn(out, valid)
             return out

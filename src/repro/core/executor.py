@@ -16,16 +16,23 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-@functools.partial(jax.jit, static_argnames=("length",))
-def _slice_chunk(src, lo, n_live, *, length):
-    """Fixed-shape device-side chunk cut: rows [lo, lo+length) of ``src``
-    plus the live-row mask ``arange(length) < n_live``.  ``length`` is static
-    (one executable per chunk shape); ``lo``/``n_live`` are traced operands,
-    so streaming a whole corpus reuses a single compiled slicer."""
+def cut_chunk(src, lo, n_live, length: int):
+    """Fixed-shape chunk cut: rows [lo, lo+length) of every leaf of ``src``
+    plus the live-row mask ``arange(length) < n_live``.  ``length`` is
+    static; ``lo``/``n_live`` may be traced, so one program serves every
+    chunk of a stream.  Traced inside the dispatcher's in-place executable
+    (the window fuses into its consumer) and inside ``_slice_chunk``."""
     sl = jax.tree_util.tree_map(
         lambda a: jax.lax.dynamic_slice_in_dim(a, lo, length, axis=0), src)
     valid = jnp.arange(length, dtype=jnp.int32) < n_live
     return sl, valid
+
+
+@functools.partial(jax.jit, static_argnames=("length",))
+def _slice_chunk(src, lo, n_live, *, length):
+    """``cut_chunk`` as its own program: the chunk is written out as a fresh
+    buffer (one executable per chunk shape)."""
+    return cut_chunk(src, lo, n_live, length)
 
 
 class DistributedExecutor:
@@ -67,15 +74,20 @@ class DistributedExecutor:
         return jax.device_put(value, self.sharding(spec))
 
     def slice_chunk(self, src, lo: int, length: int, n_live: int):
-        """Cut a fixed-shape ``length``-row chunk starting at row ``lo`` from
-        a DEVICE-resident item source, entirely on device (``lax.
+        """Copy a fixed-shape ``length``-row chunk starting at row ``lo`` out
+        of a DEVICE-resident item source, entirely on device (``lax.
         dynamic_slice`` + a valid mask for the first ``n_live`` rows) — a
         corpus produced by a previous job never round-trips to host just to
-        be re-chunked.  The caller must guarantee ``lo + length`` does not
-        exceed the source's rows (the dispatcher pads the source once, at
-        stream start); ``dynamic_slice`` would otherwise clamp ``lo`` and
-        silently shift the window.  ``lo``/``n_live`` ride into the jit as
-        weak-typed scalars — no per-chunk eager device_put."""
+        be re-chunked.  The dispatcher takes this path only where handing
+        the whole source to the job's executable would move it: a multi-
+        member mesh, a source on another device, or a deterministic job's
+        rows stage; a one-member stream over a co-located source has the
+        job's executable cut its window in place (``cut_chunk``) instead.
+        The caller must guarantee ``lo + length`` does not exceed the
+        source's rows (the dispatcher pads the source once, at stream
+        start); ``dynamic_slice`` would otherwise clamp ``lo`` and silently
+        shift the window.  ``lo``/``n_live`` ride into the jit as weak-typed
+        scalars — no per-chunk eager device_put."""
         return _slice_chunk(src, lo, n_live, length=length)
 
     def execute_on_key_owners(self, fn: Callable, data, *, out_specs=None,

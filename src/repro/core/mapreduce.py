@@ -216,9 +216,12 @@ class MapReduceEngine:
         may re-home the stream between chunks (``on_chunk`` feeds load).
         ``files`` is left as-is: a large DEVICE-resident corpus (e.g. the
         output of a previous dispatcher job; see the dispatcher's
-        ``device_slice_min_bytes``) is chunked on device by ``slice_chunk``
-        and never round-trips to host; a host (or tiny) corpus is sliced
-        host-side while the previous chunk computes (the async pipeline).
+        ``device_slice_min_bytes``) is chunked on device and never round-
+        trips to host — on one member that holds it, the job's executable
+        reads each chunk's window of it in place; across members each
+        chunk is copied out by ``slice_chunk``; a host (or tiny) corpus is
+        sliced host-side while the previous chunk computes (the async
+        pipeline).
         ``checkpoint`` (a ``core.journal.CheckpointPolicy``) makes the
         stream DURABLE: journal + pow2-aligned reduce-state checkpoints;
         after a coordinator death, ``resume_run`` continues it."""

@@ -338,6 +338,14 @@ j = DispatchJob(name="rows", signature="rows",
 x = jnp.arange(16.0, dtype=jnp.float32).reshape(8, 2)
 out, rep = d3.submit(j, x, chunk=4)
 assert rep.staged_device == rep.n_chunks == 2, rep
+assert rep.staged_in_place == 0, rep     # three members: the copy path
+assert np.array_equal(np.asarray(out), np.asarray(x) * 2.0)
+# one member whose device does not hold the source: the copy path too
+d4 = ElasticDispatcher(devices=jax.devices()[1:2], start_members=1)
+d4.device_slice_min_bytes = 0
+out, rep = d4.submit(j, x, chunk=4)
+assert rep.staged_device == rep.n_chunks == 2, rep
+assert rep.staged_in_place == 0, rep
 assert np.array_equal(np.asarray(out), np.asarray(x) * 2.0)
 print("OK")
 """], env=env, capture_output=True, text=True, timeout=900)
@@ -531,6 +539,99 @@ def test_device_resident_items_zero_host_copies(monkeypatch):
     assert host_puts == []                   # zero host copies end to end
     np.testing.assert_array_equal(np.asarray(out3),
                                   (np.asarray(items) + 2.0) * 3.0)
+
+
+def _in_place_case(case):
+    """A job and a 10-row device source for ``case`` (reduce-mode)."""
+    import jax.numpy as jnp
+    from repro.core.mapreduce import (dispatch_job_for, make_corpus,
+                                      word_count_job)
+
+    reduce, mode = case.split("-")
+    if reduce == "sum":                  # word count, int32 partials
+        backend = "hazelcast" if mode == "member" else "infinispan"
+        corpus = make_corpus(10, 256, vocab=64, seed=3)
+        return (dispatch_job_for(word_count_job(64), backend),
+                jnp.asarray(corpus),
+                np.bincount(corpus.reshape(-1), minlength=64))
+    rows = np.arange(30.0, dtype=np.float32).reshape(10, 3)
+    job = DispatchJob(name=f"rows_{mode}", signature=("rows", mode),
+                      reduce="concat",
+                      **{f"{mode}_fn": lambda x, v, *_: x * 2.0 + 1.0})
+    return job, jnp.asarray(rows), rows * 2.0 + 1.0
+
+
+@pytest.mark.parametrize("case", ["sum-member", "sum-global",
+                                  "concat-member", "concat-global"])
+def test_in_place_cut_matches_the_copy(case, monkeypatch):
+    """One member over a device source on its own device: the job's
+    executable cuts each window itself, bit-identical to the
+    ``slice_chunk`` copy — the ragged last chunk included (10 rows in
+    chunks of 4, over a source padded to 12).  The source reads back
+    unchanged after the stream, and a second stream over it gives the
+    same bytes from the cached executable."""
+    job, items, want = _in_place_case(case)
+    before = np.asarray(items)
+    d = ElasticDispatcher(start_members=1)
+    d.device_slice_min_bytes = 0
+    out, rep = d.submit(job, items, chunk=4)
+    assert rep.staged_in_place == rep.staged_device == rep.n_chunks == 3
+    assert rep.staged_host == 0
+    assert not items.is_deleted()
+    np.testing.assert_array_equal(np.asarray(items), before)
+    out2, rep2 = d.submit(job, items, chunk=4)
+    assert rep2.compiles == 0 and rep2.staged_in_place == 3
+
+    monkeypatch.setattr(ElasticDispatcher, "_reads_in_place",
+                        lambda *a: False)
+    dc = ElasticDispatcher(start_members=1)
+    dc.device_slice_min_bytes = 0
+    ref, rep_copy = dc.submit(job, items, chunk=4)
+    assert rep_copy.staged_in_place == 0
+    assert rep_copy.staged_device == rep_copy.n_chunks == 3
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+@pytest.mark.parametrize("mode", ["member", "global"])
+def test_in_place_source_is_never_donated(mode):
+    """A source exactly one chunk long has the output's shape and dtype,
+    so a donated source would be taken over by the output and deleted: it
+    must read back after the stream, twice over."""
+    import jax.numpy as jnp
+
+    job, _, _ = _in_place_case(f"concat-{mode}")
+    rows = np.arange(12.0, dtype=np.float32).reshape(4, 3)
+    src = jnp.asarray(rows)
+    d = ElasticDispatcher(start_members=1)
+    d.device_slice_min_bytes = 0
+    for _ in range(2):
+        out, rep = d.submit(job, src, chunk=4)
+        assert rep.staged_in_place == rep.n_chunks == 1
+        assert not src.is_deleted()
+        np.testing.assert_array_equal(np.asarray(src), rows)
+        np.testing.assert_array_equal(np.asarray(out), rows * 2.0 + 1.0)
+
+
+def test_in_place_replay_reads_the_same_window():
+    """A chunk poisoned under the fault injector is launched again with
+    its ``lo`` and reads the same window of the source: the stream comes
+    out as the fault-free one, every launch cut in place."""
+    import jax.numpy as jnp
+    from repro.core.faults import FaultInjector, FaultSpec
+
+    job = DispatchJob(name="rows_f", signature="rows_f", reduce="concat",
+                      member_fn=lambda x, v, *_: x * 2.0 + 1.0)
+    items = jnp.arange(30.0, dtype=jnp.float32).reshape(10, 3)
+    d = ElasticDispatcher(start_members=1, fault_injector=FaultInjector(
+        [FaultSpec("nan_poison", chunk=1, member=0)]))
+    d.device_slice_min_bytes = 0
+    out, rep = d.submit(job, items, chunk=4)
+    assert [f["chunk"] for f in rep.failures] == [1] and rep.retries == 1
+    assert rep.staged_in_place == rep.staged_device == rep.n_chunks + 1
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(items) * 2.0 + 1.0)
 
 
 def test_deterministic_sum_requires_sum_reduce():

@@ -10,6 +10,7 @@ backend here is the CPU.  The topology is described inside a fixture, never
 at import: only one process may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +137,58 @@ def test_word_count_map_lowers_per_vocab(one_chip, vocab, path):
                    _shape(one_chip, (8,), jnp.bool_))
     other = {"convolution": "scatter", "scatter": "convolution"}[path]
     assert path in hlo and other not in hlo
+
+
+def _entry_arrays(hlo):
+    """(dtype, elements) of each array an instruction of ``hlo``'s ENTRY
+    computation writes.  Parameters are read, not written, and the values
+    inside a fusion's body never leave the chip's fast memory."""
+    body = hlo[hlo.index("\nENTRY"):]
+    body = body[:body.index("\n}")]
+    arrays = []
+    for line in body.splitlines()[1:]:
+        rhs = line.partition(" = ")[2]
+        if not rhs or " parameter(" in rhs:
+            continue
+        if rhs.startswith("("):          # a tuple: up to its closing paren
+            depth = 0
+            for end, ch in enumerate(rhs):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+            typ = rhs[:end + 1]
+        else:
+            typ = rhs.split(" ", 1)[0]
+        for dtype, dims in re.findall(r"([a-z]+\d*)\[([\d,]*)\]", typ):
+            arrays.append((dtype, int(np.prod(
+                [int(d) for d in dims.split(",") if d]))))
+    return arrays
+
+
+def test_word_count_cuts_its_chunk_in_place(topo, one_chip):
+    """The dispatcher's in-place executable for word count, compiled for
+    the chip with a chunk of 8 files of 65,536 ids out of a source of 32:
+    the window's dynamic slice fuses into the pass that narrows the ids,
+    so nothing writes an int32 array of the chunk's size.  The copy
+    program (``_slice_chunk``) is the control: it writes one."""
+    from repro.core.dispatch import ElasticDispatcher
+    from repro.core.executor import _slice_chunk
+    from repro.core.mapreduce import dispatch_job_for, word_count_job
+
+    rows, width, L = 32, 65536, 8
+    src = _shape(one_chip, (rows, width), jnp.int32)
+    d = ElasticDispatcher(devices=topo.devices[:1], start_members=1)
+    job = dispatch_job_for(word_count_job(1000))
+    assert d._reads_in_place(job, src)
+    fn = d._executable(job, src, (), L, in_place=True)
+    hlo = fn.lower(src, 0, L).compile().as_text()
+    copy = _slice_chunk.lower(src, 0, L, length=L).compile().as_text()
+
+    def chunk_sized(text):
+        return [a for a in _entry_arrays(text)
+                if a[0] == "s32" and a[1] >= L * width]
+
+    assert hlo.startswith("HloModule jit_dispatch_mapreduce_word_count")
+    assert "convolution" in hlo               # the MXU map, as on the chip
+    assert chunk_sized(hlo) == []
+    assert ("s32", L * width) in chunk_sized(copy)
