@@ -314,29 +314,13 @@ def _chunk_tree_reduce(parts, combine, pending=None):
 
 # ------------------------------------------------------- failure detection
 
-def _all_finite(tree) -> bool:
-    """Cheap post-retirement health probe: True iff every float leaf of a
-    chunk output is fully finite — the ``HealthMonitor`` docstring's "member
-    crash" signal.  One device reduction + one scalar sync per float leaf on
-    an ALREADY-RETIRED output (int leaves cannot encode NaN/Inf and are
-    skipped); the fault-free overhead is benchmarked in BENCH_fault.json."""
-    for leaf in jax.tree_util.tree_leaves(tree):
-        if isinstance(leaf, np.ndarray):
-            if leaf.dtype.kind == "f" and not np.isfinite(leaf).all():
-                return False
-        elif jnp.issubdtype(leaf.dtype, jnp.floating):
-            if not bool(jnp.isfinite(leaf).all()):
-                return False
-    return True
-
-
 @jax.jit
 def _finite_probe(tree):
     """One fused all-float-leaves-finite reduction, ENQUEUED at launch so it
     overlaps the pipelined compute it guards — the validator only syncs the
     resulting scalar, which by retirement time has already been computed.
-    Keeps the fault-free guarded overhead (BENCH_fault.json) to one device
-    scalar sync per chunk instead of per-leaf blocking round-trips."""
+    Keeps an armed stream's fault-free overhead (BENCH_fault.json) to one
+    device scalar sync per chunk instead of per-leaf blocking round-trips."""
     flags = [jnp.isfinite(leaf).all()
              for leaf in jax.tree_util.tree_leaves(tree)
              if jnp.issubdtype(leaf.dtype, jnp.floating)]
@@ -553,8 +537,8 @@ class ElasticDispatcher:
         self.cache = CompileCache(cache_entries)
         self.chunk_size = chunk_size
         self.auto_scale = auto_scale
-        # pipeline depth: how many chunks may be launched ahead of the oldest
-        # unretired one (0 = fully synchronous, the pre-async baseline)
+        # pipeline depth: how many chunks may stay launched but unretired
+        # (0 retires each chunk right after its launch)
         self.dispatch_ahead = max(int(dispatch_ahead), 0)
         # device-resident item sets at least this big are chunked on device
         # (in place, or by ``executor.slice_chunk``) instead of round-
@@ -585,9 +569,9 @@ class ElasticDispatcher:
         # failure-path calibration reset, self-calibrated ones do not
         self.job_targets: Dict[Hashable, float] = {}
         self._explicit_targets: set = set()
-        # launched-but-unretired chunk outputs of the ACTIVE stream; the
-        # remesh barrier drains it, exception cleanup clears it
-        self._in_flight: Deque[Tuple] = collections.deque()
+        # the stream ``submit`` is running (None between streams): the
+        # remesh barrier drains its flight queue
+        self._active_stream: Optional[_Stream] = None
         self._valid_masks: Dict[Tuple[int, int], jnp.ndarray] = {}
         self._epoch = 0                  # bumped per remesh (geometry epoch)
         self._build(n0)
@@ -739,22 +723,15 @@ class ElasticDispatcher:
         """Launched-but-unretired chunks of the active stream (0 between
         streams — the exception-safety observable: a failed ``submit`` must
         never leak launched buffers)."""
-        return len(self._in_flight)
+        stream = self._active_stream
+        return 0 if stream is None else len(stream.flight)
 
     def _drain_in_flight(self) -> int:
-        """Block until every launched chunk has retired.  Returns how many
-        were in flight — the remesh barrier records it per scale event.
-        Exception-safe: if a chunk's computation itself raises at the
-        blocking point, the rest of the queue is still dropped — a stale
-        chunk must never leak into (and re-raise inside) the next stream."""
-        n = len(self._in_flight)
-        try:
-            while self._in_flight:
-                _, out, _, _ = self._in_flight.popleft()
-                jax.block_until_ready(out)
-        finally:
-            self._in_flight.clear()
-        return n
+        """Block until every launched chunk of the active stream has
+        retired.  Returns how many were in flight (0 between streams) —
+        the remesh barrier records it per scale event."""
+        stream = self._active_stream
+        return 0 if stream is None else stream.drain_in_flight()
 
     def calibrate_target(self, job: DispatchJob, target_step_time: float
                          ) -> None:
@@ -886,13 +863,7 @@ class ElasticDispatcher:
         if state.header is None:
             raise ResumeMismatchError(f"no journal header at {path!r} — "
                                       "nothing to resume")
-        leaves = jax.tree_util.tree_leaves(items)
-        if not leaves:
-            raise ValueError("resume needs the original item arrays")
-        B = int(leaves[0].shape[0])
-        chunk_ = chunk if chunk is not None else (self.chunk_size or B)
-        chunk_ = max(1, min(int(chunk_), max(B, 1)))
-        n_chunks = max(-(-B // chunk_), 1)
+        _, B, chunk_, n_chunks = self._chunk_plan(items, chunk)
         mine = self._env_signature(job, B, chunk_, n_chunks, items,
                                    replicated)
         theirs = state.header.get("env", {})
@@ -972,13 +943,13 @@ class ElasticDispatcher:
         Pipelining: chunk k+1 is staged (sliced + padded) and dispatched
         while chunk k still runs on device — JAX dispatch is asynchronous,
         so the host never blocks mid-stream except to (1) bound the queue at
-        ``dispatch_ahead`` launched-but-unretired chunks (memory bound;
-        0 = fully synchronous baseline) and (2) take the wall-time samples
-        the IAS needs.  The only other synchronization points are the
-        REMESH BARRIER (``_remesh`` drains the queue before rebuilding) and
-        the final reduce.  Chunk boundaries and reduce order never depend on
-        how many chunks were in flight, so results are bit-identical to the
-        synchronous path for every scale sequence.
+        ``dispatch_ahead`` launched-but-unretired chunks (memory bound; 0
+        retires each chunk right after its launch) and (2) take the
+        wall-time samples the IAS needs.  The only other synchronization
+        points are the REMESH BARRIER (``_remesh`` drains the queue before
+        rebuilding) and the final reduce.  Chunk boundaries and reduce order
+        never depend on how many chunks were in flight, so results are
+        bit-identical at every depth for every scale sequence.
 
         Staging: a DEVICE-resident item set (every leaf a ``jax.Array``) of
         at least ``device_slice_min_bytes`` never round-trips to host — the
@@ -1012,17 +983,17 @@ class ElasticDispatcher:
         gather instead of a sharded device concat PLUS a gather; the values
         are bitwise identical either way).
 
-        Fault tolerance: ``retry_policy`` / ``fault_injector`` (falling back
-        to the dispatcher-level defaults) arm the GUARDED retirement path —
-        every chunk is validated on retirement (deadline, optional finite
-        check), detected failures are retried under the policy's budget,
-        repeat-offender members are quarantined via a forced failure remesh,
-        and the failed plus lost in-flight chunks are REPLAYED; because the
-        combine below walks chunk INDEX order, a recovered stream is
-        bit-identical to a fault-free run.  Without either, the fault-free
-        fast path is byte-for-byte the unguarded pipeline.  Unrecoverable
-        streams raise ``JobFailedError`` carrying the report.  Returns
-        ``(outputs, DispatchReport)``.
+        Fault tolerance: every chunk is validated once it retires.  An
+        active ``retry_policy`` or a ``fault_injector`` (falling back to the
+        dispatcher-level defaults) ARMS the validation — deadline, optional
+        finite check — so detected failures are retried under the policy's
+        budget, repeat-offender members are quarantined via a forced failure
+        remesh, and the failed plus lost in-flight chunks are REPLAYED;
+        because the combine walks chunk INDEX order, a recovered stream is
+        bit-identical to a fault-free run.  Unarmed, validation only stamps
+        the stats and journals the chunk.  Unrecoverable streams raise
+        ``JobFailedError`` carrying the report.  Returns ``(outputs,
+        DispatchReport)``.
 
         Durability: ``checkpoint`` (a ``CheckpointPolicy``, falling back to
         the dispatcher default) journals the stream — header with the
@@ -1041,41 +1012,7 @@ class ElasticDispatcher:
         """
         if deliver not in ("device", "host"):
             raise ValueError(f"unknown deliver {deliver!r}")
-        if tenant is not None:
-            # tenant-scoped stream: bind the fault injector so tenant-
-            # addressed specs fire only inside THIS stream (replays
-            # included), and tag the report — JobFailedError reports too,
-            # so a failed tenant's post-mortem names its owner
-            inj = (fault_injector if fault_injector is not None
-                   else self.fault_injector)
-            ctx = (inj.bind_tenant(tenant) if inj is not None
-                   else contextlib.nullcontext())
-            try:
-                with ctx:
-                    out, rep = self.submit(
-                        job, items, replicated=replicated, chunk=chunk,
-                        on_chunk=on_chunk, dispatch_ahead=dispatch_ahead,
-                        deliver=deliver, retry_policy=retry_policy,
-                        fault_injector=fault_injector,
-                        collect_stats=collect_stats, checkpoint=checkpoint,
-                        _resume=_resume)
-            except JobFailedError as e:
-                e.report.tenant = tenant
-                raise
-            rep.tenant = tenant
-            return out, rep
-        leaves = jax.tree_util.tree_leaves(items)
-        if not leaves:
-            raise ValueError("submit needs at least one item array")
-        B = int(leaves[0].shape[0])
-        if any(int(l.shape[0]) != B for l in leaves):
-            raise ValueError("item arrays must share their leading dim")
-        chunk = chunk if chunk is not None else (self.chunk_size or B)
-        chunk = max(1, min(int(chunk), max(B, 1)))
-        # B == 0 still runs ONE fully-padded chunk (valid all-False): concat
-        # outputs trim to correct empty arrays, sum/max partials reduce over
-        # masked-out rows only — parity with the non-dispatcher vmap path
-        n_chunks = max(-(-B // chunk), 1)
+        leaves, B, chunk, n_chunks = self._chunk_plan(items, chunk)
         # per-stage queueing stats: enqueue → dispatch → retire → validate
         # stamps per chunk, plus the stage spans.  Collection never touches
         # chunk payloads or reduce order (results stay bit-identical); the
@@ -1086,16 +1023,26 @@ class ElasticDispatcher:
         collector = (DispatchStats(warmup=self.health_cfg.stats_warmup,
                                    cooldown=self.health_cfg.stats_cooldown)
                      if collect or self.health_cfg.policy == "mmn" else None)
+        # a tenant-scoped stream binds the fault injector, so tenant-
+        # addressed specs fire only inside THIS stream (replays included)
+        inj = (fault_injector if fault_injector is not None
+               else self.fault_injector)
+        bound = (inj.bind_tenant(tenant) if tenant is not None
+                 and inj is not None else contextlib.nullcontext())
         sid = next(_STREAM_IDS)
         jax0 = jax_counts()
-        with span("dispatch.stream", collector, job=job.name,
-                  n_chunks=n_chunks, stream=sid):
-            outputs, report = self._stream(
-                job, items, leaves, B, chunk, n_chunks, collector, sid,
-                replicated=replicated, on_chunk=on_chunk,
-                dispatch_ahead=dispatch_ahead, deliver=deliver,
-                retry_policy=retry_policy, fault_injector=fault_injector,
-                checkpoint=checkpoint, _resume=_resume)
+        with bound, span("dispatch.stream", collector, job=job.name,
+                         n_chunks=n_chunks, stream=sid):
+            try:
+                self._active_stream = _Stream(
+                    self, job, items, leaves, B, chunk, n_chunks, collector,
+                    sid, replicated=replicated, on_chunk=on_chunk,
+                    dispatch_ahead=dispatch_ahead, deliver=deliver,
+                    retry_policy=retry_policy, fault_injector=inj,
+                    checkpoint=checkpoint, resume=_resume, tenant=tenant)
+                outputs, report = self._active_stream.run()
+            finally:
+                self._active_stream = None
         jax1 = jax_counts()
         report.jax_traces = jax1["traces"] - jax0["traces"]
         report.jax_compiles = jax1["compiles"] - jax0["compiles"]
@@ -1104,675 +1051,20 @@ class ElasticDispatcher:
             report.stats = collector.summary(n_servers=1)
         return outputs, report
 
-    def _stream(self, job: DispatchJob, items, leaves, B: int, chunk: int,
-                n_chunks: int, collector: Optional[DispatchStats], sid: int,
-                *, replicated, on_chunk, dispatch_ahead, deliver,
-                retry_policy, fault_injector, checkpoint, _resume
-                ) -> Tuple[object, DispatchReport]:
-        """The body of ``submit``'s chunk stream (``sid``: its span id),
-        inside its ``dispatch.stream`` span."""
-        depth = (self.dispatch_ahead if dispatch_ahead is None
-                 else max(int(dispatch_ahead), 0))
-        # device-side chunk slicing pays one extra jit dispatch per chunk to
-        # save the host round-trip — worth it exactly when the item set is
-        # big enough for the copies to matter.  Tiny item sets (a grid's
-        # per-variant scalars) stage faster through numpy.  depth 0
-        # reproduces the legacy synchronous path end to end: items round-
-        # trip through host numpy exactly as the pre-async dispatcher staged
-        # them.
-        n_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
-        on_device = (depth > 0 and B > 0
-                     and n_bytes >= self.device_slice_min_bytes
-                     and all(isinstance(l, jax.Array) for l in leaves))
-        if on_device:
-            src = self._pad_device_source(items, chunk, n_chunks, B)
-        else:
-            items_np = jax.tree_util.tree_map(np.asarray, items)
-
-        policy = (retry_policy if retry_policy is not None
-                  else self.retry_policy)
-        injector = (fault_injector if fault_injector is not None
-                    else self.fault_injector)
-        if policy is None:
-            # an injector without an explicit policy still needs a detector:
-            # default attempt budget with the finiteness probe armed
-            policy = RetryPolicy(check_finite=injector is not None)
-        guarded = injector is not None or policy.active
-        mmn = self.health_cfg.policy == "mmn"
-        launch_epoch: Dict[int, int] = {}  # chunk -> epoch at its launch
-        if job.deterministic and n_chunks > 1 and chunk & (chunk - 1) != 0:
-            warnings.warn(
-                f"deterministic float sum chunked at {chunk} (not a power of"
-                " two): results are deterministic and replay-stable for THIS"
-                " chunking but not bit-identical across chunk sizes — use a"
-                " power-of-two chunk for the cross-chunking guarantee",
-                NonPow2ChunkWarning, stacklevel=2)
-
-        report = DispatchReport(job=job.name, n_items=B, chunk=chunk,
-                                n_chunks=n_chunks, dispatch_ahead=depth,
-                                kernel_path=job.kernel_path,
-                                map_path=self._map_path(job))
-        hits0, builds0 = self.cache.hits, self.cache.builds
-        events0 = len(self.scale_events)
-        # durability: open (or adopt, on resume) the stream's journal and
-        # track the checkpointable validated prefix.  ``ck`` holds the
-        # durable reduce state: k = folded prefix length, state = the
-        # binary-counter pending dict (sum/max) or concatenated prefix
-        # (concat), done = journaled chunk indices, host = validated host
-        # copies awaiting the next fold, digests = journaled digests a
-        # resumed run re-verifies its replays against.
-        ckpolicy = (checkpoint if checkpoint is not None
-                    else self.checkpoint_policy)
-        journal: Optional[JobJournal] = None
-        ck: Optional[dict] = None
-        base_k = 0
-        if ckpolicy is not None:
-            if _resume is not None:
-                journal = _resume["journal"]
-                base_k = int(_resume["base_k"])
-                report.resumed_from = _resume["path"]
-                report.chunks_skipped = base_k
-                report.chunks_replayed = n_chunks - base_k
-                base_state = _resume["base_state"]
-            else:
-                env = self._env_signature(job, B, chunk, n_chunks, items,
-                                          replicated)
-                journal = JobJournal.create(ckpolicy, {
-                    "env": env, "n_members": self.n_members,
-                    "owner": self.table.owner.tolist(),
-                    "every_n_chunks": ckpolicy.every_n_chunks})
-                base_state = None
-            ck = {"k": base_k, "state": base_state, "done": set(),
-                  "host": {}, "digests": dict(_resume["digests"])
-                  if _resume is not None else {},
-                  "stride": ckpolicy.every_n_chunks, "n_scale": 0}
-            report.journal_path = journal.path
-        # per-chunk results indexed by chunk: trimmed row outputs (concat) or
-        # partial aggregates (sum/max/deterministic).  A REPLAY overwrites
-        # its chunk's slot; the combine walks slots in chunk-index order, so
-        # retries and recoveries never perturb the reduce tree.  A resumed
-        # stream fills only slots >= base_k — the skipped prefix lives in
-        # the restored checkpoint state.
-        parts: List[Optional[Tuple[int, object]]] = [None] * n_chunks
-        part_epochs = set()  # geometries the parts live on
-        alpha = getattr(self.health_cfg, "ema_alpha", 0.4)
-        stream = {"t_mark": None, "ema": None, "epoch": self._epoch}
-        queue: Deque[int] = collections.deque(range(base_k, n_chunks))
-        if collector is not None:
-            # a submit stream is a CLOSED arrival process: every chunk is
-            # ready at stream start, so they share one enqueue stamp and
-            # queue_wait measures time spent behind the pipeline bound
-            t0_enq = collector.clock()
-            for _ci in range(base_k, n_chunks):
-                collector.enqueue(_ci, t0_enq)
-        fired_cb: set = set()             # chunks whose on_chunk has run
-        attempts: Dict[int, int] = collections.Counter()
-        strikes: Dict = collections.Counter()  # retryable failures / device
-        # retired-but-unvalidated chunks (guarded path): mirrors _in_flight
-        # plus whatever a barrier drained before validation could run
-        pending_val: Deque[Tuple] = collections.deque()
-        open_recoveries: List[dict] = []  # member recoveries awaiting replays
-        fail_t: Dict[int, float] = {}     # chunk -> last failure detect time
-        val_step = [0]
-        # unguarded journaled streams: launched chunks not yet journaled —
-        # a remesh barrier retires in-flight chunks without passing through
-        # retire_oldest, so journal_settled sweeps them up afterwards
-        unjournaled: set = set()
-
-        def journal_scales():
-            """Journal scale events fired since the last call, each with the
-            post-event member count and partition-owner snapshot — what
-            ``resume`` rebuilds the topology from."""
-            while journal is not None and \
-                    ck["n_scale"] < len(self.scale_events) - events0:
-                ev = self.scale_events[events0 + ck["n_scale"]]
-                journal.append({"type": "scale", "event": ev,
-                                "n_members": self.n_members,
-                                "owner": self.table.owner.tolist()})
-                ck["n_scale"] += 1
-
-        def advance_checkpoint(force: bool = False):
-            """Fold newly-contiguous validated chunks into the durable
-            reduce state and persist it (atomic dir) when a stride boundary
-            is crossed — or at the exact watermark when a drain forces it.
-            The binary-counter state after ANY validated prefix k is exactly
-            the pow2 subtrees of k's binary decomposition, so every
-            checkpoint is an exact subtree state of the deterministic chunk
-            tree and resume is bit-identical.  Runs on the journal WRITER
-            thread (the tail of each ``finish_chunk``); the drain path is
-            the one dispatch-thread caller, and only after ``journal.wait``
-            has idled the queue."""
-            w = ck["k"]
-            while w in ck["host"]:
-                w += 1
-            boundary = w if force else (w // ck["stride"]) * ck["stride"]
-            if boundary <= ck["k"] or (not force and boundary >= n_chunks):
-                return                   # completion writes the final state
-            if job.reduce == "concat":
-                pieces = ([] if ck["state"] is None else [ck["state"]])
-                pieces += [ck["host"].pop(ci)
-                           for ci in range(ck["k"], boundary)]
-                ck["state"] = jax.tree_util.tree_map(
-                    lambda *xs: np.concatenate(xs, axis=0), *pieces)
-                kind = "prefix"
-            else:
-                combine = np.add if job.reduce == "sum" else np.maximum
-                # shallow-copy so a resume's restored base dict is never
-                # mutated — the final combine still needs it untouched
-                pending = dict(ck["state"] or {})
-                for ci in range(ck["k"], boundary):
-                    counter_push(pending, ck["host"].pop(ci), combine)
-                ck["state"] = pending
-                kind = "pending"
-            ck["k"] = boundary
-            journal.checkpoint_now(boundary, kind, ck["state"],
-                                   {"n_members": self.n_members})
-
-        def finish_chunk(ci: int, out, n_live: int, record: dict):
-            """Writer-thread tail of ``journal_chunk``: gather the validated
-            partial to host (trimmed for concat), digest it, write the chunk
-            record, stage the host copy for the fold, and advance the
-            checkpoint watermark.  Everything here walks output bytes —
-            keeping it off the dispatch thread is what makes fault-free
-            journaling overhead a queue put per chunk."""
-            host = jax.tree_util.tree_map(np.asarray, out)
-            if job.reduce == "concat":
-                host = jax.tree_util.tree_map(lambda a: a[:n_live], host)
-            if "digest" not in record:
-                record["digest"] = tree_digest(host)
-            journal.sync_append(record)
-            ck["host"][ci] = host
-            advance_checkpoint()
-
-        def journal_chunk(ci: int):
-            """Close the durable books on one FINAL chunk (validated on the
-            guarded path, retired on the unguarded one).  The heavy tail —
-            host gather, digest, fold, checkpoint — rides the journal
-            writer thread via ``defer``; only a resumed replay digests HERE,
-            inline, because a divergent replay must stop the stream
-            immediately, not surface after more chunks launched."""
-            if journal is None or ci in ck["done"] or ci < base_k:
-                return
-            n_live, out = parts[ci]
-            record = {"type": "chunk", "chunk": int(ci),
-                      "attempt": int(attempts[ci]), "n_live": int(n_live)}
-            expect = ck["digests"].get(ci)
-            if expect is not None:
-                host = jax.tree_util.tree_map(np.asarray, out)
-                if job.reduce == "concat":
-                    host = jax.tree_util.tree_map(lambda a: a[:n_live],
-                                                  host)
-                digest = tree_digest(host)
-                if digest != expect:
-                    raise ResumeMismatchError(
-                        f"replayed chunk {ci} digest {digest[:12]}… does "
-                        f"not match the journaled {expect[:12]}… — the "
-                        "items or job differ from the journaled stream")
-                record["digest"] = digest
-                out = host               # gathered once; the fold reuses it
-            elif not ckpolicy.digest_chunks:
-                record["digest"] = None
-            journal.defer(lambda c=ci, o=out, nl=n_live, r=record:
-                          finish_chunk(c, o, nl, r))
-            ck["done"].add(ci)
-            unjournaled.discard(ci)
-            journal_scales()
-
-        def journal_settled():
-            """Unguarded path only: journal launched chunks that have left
-            the flight queue without passing through ``retire_oldest`` — a
-            remesh barrier's ``_drain_in_flight`` blocks until they are
-            ready, so anything launched and no longer in flight is FINAL."""
-            if journal is None or guarded or not unjournaled:
-                return
-            flying = {entry[0] for entry in self._in_flight}
-            for ci in sorted(unjournaled - flying):
-                journal_chunk(ci)
-
-        def drain_now():
-            """Graceful preemption (``request_drain``/SIGTERM): stop
-            launching, retire + validate everything in flight, checkpoint
-            the exact validated watermark, journal the drain, and raise
-            ``DrainInterrupted`` — ``resume`` continues the stream later."""
-            self._drain_requested.clear()
-            while self._in_flight:
-                retire_oldest()
-            if guarded:
-                sync_validation()
-            journal_settled()
-            journal_scales()
-            journal.wait()               # settle the deferred fold tails so
-            # ck["k"]/["state"] are this thread's to touch
-            advance_checkpoint(force=True)
-            journal.append({"type": "drain", "k": int(ck["k"]),
-                            "remaining": sorted(queue)}, fsync=True)
-            journal.wait()
-            report.checkpoints = journal.n_checkpoints
-            report.checkpoint_write_s = list(journal.write_s)
-            if collector is not None:
-                for w_s in journal.write_s:
-                    collector.record_checkpoint(w_s)
-                report.stats = collector.summary(n_servers=1)
-            report.wall_s = time.perf_counter() - t_start
-            journal.close()
-            raise DrainInterrupted(
-                f"stream of job {job.name!r} drained at validated prefix "
-                f"{ck['k']}/{n_chunks} on request", report, journal.path)
-
-        def mark(compiled: bool, t_launch: float):
-            """Sample one per-chunk step time — the retirement-to-retirement
-            wall delta in pipelined steady state, or launch-to-completion
-            when nothing retired before this chunk (short streams) — and,
-            under auto_scale, feed EMA/target to the IAS.  Compile chunks
-            and remesh barriers reset the timer instead of polluting the
-            EMA — their wall is trace/compile or rebuild noise, often
-            10-100x the steady state, and would ratchet the scaler to
-            max_instances."""
-            now = time.perf_counter()
-            if compiled or stream["epoch"] != self._epoch:
-                stream["epoch"] = self._epoch
-                stream["t_mark"] = now
-                return
-            since = (t_launch if stream["t_mark"] is None
-                     else max(stream["t_mark"], t_launch))
-            dt, stream["t_mark"] = now - since, now
-            stream["ema"] = (dt if stream["ema"] is None
-                             else alpha * dt + (1.0 - alpha) * stream["ema"])
-            report.ema_step_s = stream["ema"]
-            if self.auto_scale and on_chunk is None:
-                if mmn and collector is not None:
-                    # queue-aware feed: measured per-member service rate vs
-                    # the demand anchor 1/target.  Closed streams have no
-                    # meaningful arrival process, so queue_length stays 0 —
-                    # backlog is pipeline structure, not unmet demand (open
-                    # callers like serve/ pass a measured Lq themselves).
-                    s = collector.mean_service()
-                    if math.isfinite(s) and s > 0:
-                        target = self._job_target(job, s)
-                        self.controller.tick_queue(QueueSnapshot(
-                            arrival_rate=1.0 / target,
-                            service_rate=1.0 / (s * self.n_members),
-                            n_members=self.n_members,
-                            queue_length=0.0))
-                else:
-                    self.observe_load(stream["ema"]
-                                      / self._job_target(job, stream["ema"]))
-
-        def retire_oldest():
-            """Block on the oldest launched chunk, then sample; the guarded
-            path validates every chunk that has left the flight queue."""
-            ci, out, compiled, t_launch = self._in_flight.popleft()
-            with span("dispatch.retire", collector, stream=sid, chunk=ci):
-                jax.block_until_ready(out)
-            if collector is not None:
-                # stamp BEFORE mark() so the mmn feed sees a fresh mean
-                tainted = compiled or launch_epoch.get(ci) != self._epoch
-                collector.retire(ci, tainted=tainted)
-                if not guarded:
-                    collector.validate(ci, tainted=tainted)
-            mark(compiled, t_launch)
-            if guarded:
-                sync_validation()
-            elif journal is not None:
-                journal_chunk(ci)        # unguarded: retirement is final
-
-        def note_validated(ci: int, now: float):
-            """Close the books on a validated chunk: stamp the recovery
-            latency on its latest failure record and on any open
-            member-failure recovery awaiting its replay."""
-            t0 = fail_t.pop(ci, None)
-            if t0 is not None:
-                for rec in reversed(report.failures):
-                    if rec["chunk"] == ci and "recovered_after_s" not in rec:
-                        rec["recovered_after_s"] = now - t0
-                        break
-            for open_rec in open_recoveries[:]:
-                open_rec["outstanding"].discard(ci)
-                if not open_rec["outstanding"]:
-                    open_rec["event"]["recovery_s"] = now - open_rec["t0"]
-                    open_recoveries.remove(open_rec)
-            journal_chunk(ci)            # guarded: validation is final
-
-        def recover_member(device, slot: int, failed_ci: int, cause: str):
-            """Member-failure recovery: the replay set is the failed chunk
-            plus every launched-but-unvalidated chunk (their buffers may
-            live on the dead member); drain the survivors, force the
-            failure remesh, and requeue the replays in ascending order."""
-            t0 = time.perf_counter()
-            lost = sorted({failed_ci}
-                          | {entry[0] for entry in pending_val}
-                          | {entry[0] for entry in self._in_flight})
-            self._drain_in_flight()
-            pending_val.clear()
-            strikes.pop(device, None)
-            event = self._member_failure_remesh(device, slot, report)
-            event.update({"cause": cause, "dead_member": slot,
-                          "dead_device": str(device),
-                          "failed_chunk": failed_ci,
-                          "replayed_chunks": lost})
-            report.recovery_events.append(event)
-            report.retries += len(lost)
-            open_recoveries.append(
-                {"event": event, "t0": t0, "outstanding": set(lost)})
-            for ci in reversed(lost):
-                queue.appendleft(ci)
-                if collector is not None:
-                    collector.enqueue(ci)
-
-        def fail_chunk(ci: int, kind: str, member=None, detail: str = "",
-                       wall=None):
-            """Record one retryable chunk failure, enforce the attempt
-            budget, quarantine a repeat-offender member, back off, and
-            requeue the chunk for replay."""
-            attempts[ci] += 1
-            fail_t[ci] = time.perf_counter()
-            report.failures.append(
-                {"chunk": ci, "kind": kind, "attempt": attempts[ci],
-                 "member": member, "detail": detail, "wall_s": wall})
-            if journal is not None:      # retry/fault events are durable too
-                journal.append({"type": "fault", "chunk": int(ci),
-                                "kind": kind, "attempt": int(attempts[ci]),
-                                "member": member, "detail": detail})
-            if attempts[ci] >= policy.max_attempts:
-                raise JobFailedError(
-                    f"chunk {ci} of job {job.name!r} failed {attempts[ci]}x"
-                    f" (last: {kind}); attempts exhausted (max_attempts="
-                    f"{policy.max_attempts})", report)
-            if member is not None and policy.quarantine_after > 0:
-                mesh_devices = self.executor.device_list
-                dev = mesh_devices[member % len(mesh_devices)]
-                strikes[dev] += 1
-                # quarantine only when the pool can afford to lose the
-                # member; otherwise keep retrying under the attempt budget
-                can_drop = (len(self.devices) - 1
-                            >= max(1, self.health_cfg.min_instances))
-                if strikes[dev] >= policy.quarantine_after and can_drop:
-                    recover_member(
-                        dev, member, ci,
-                        cause=(f"quarantined: {strikes[dev]} retryable "
-                               f"failures attributed to one member "
-                               f"(last: {kind})"))
-                    return
-            report.retries += 1
-            backoff = policy.backoff_for(attempts[ci])
-            if backoff > 0:
-                time.sleep(backoff)
-            queue.appendleft(ci)
-            if collector is not None:
-                collector.enqueue(ci)
-
-        def validate(ci, out, t_launch, M, L, fin=None, compiled=False):
-            """Guarded retirement: fire any scheduled stall, take the
-            chunk's wall, sync the finiteness probe (``fin``, enqueued at
-            launch — falls back to a blocking ``_all_finite`` when no probe
-            was dispatched), feed the detector monitor, and route detected
-            failures to ``fail_chunk``."""
-            delay, stall_slot = (injector.stall_for(ci) if injector
-                                 else (0.0, None))
-            if delay > 0:
-                time.sleep(delay)         # the hung launch: retirement late
-            now = time.perf_counter()
-            wall = now - t_launch
-            tainted = compiled or launch_epoch.get(ci) != self._epoch
-            finite = True
-            if policy.check_finite or injector is not None:
-                finite = bool(fin) if fin is not None else _all_finite(out)
-            member_times = None
-            if stall_slot is not None:
-                member_times = [max(wall - delay, 0.0)] * M
-                member_times[stall_slot % M] = wall
-            val_step[0] += 1
-            self.fault_monitor.observe_chunk(
-                step=val_step[0], wall_s=wall, finite=finite,
-                member_times=member_times, tainted=tainted)
-            if collector is not None and delay > 0:
-                collector.record_stall(delay)
-            if not finite:
-                if collector is not None:
-                    # a failed attempt's wall is fault noise: keep the
-                    # record's time integrals, drop it from the windows
-                    collector.validate(ci, t=now, tainted=True)
-                fail_chunk(ci, "nan_poison",
-                           member=_nonfinite_member(out, L, M),
-                           detail="non-finite chunk output", wall=wall)
-                return
-            if (policy.chunk_timeout_s is not None
-                    and wall > policy.chunk_timeout_s):
-                if collector is not None:
-                    collector.validate(ci, t=now, tainted=True)
-                fail_chunk(
-                    ci, "stall", member=stall_slot,
-                    detail=(f"wall {wall:.3f}s exceeded deadline "
-                            f"{policy.chunk_timeout_s}s (straggler skew "
-                            f"{self.fault_monitor.straggler_skew():.2f})"),
-                    wall=wall)
-                return
-            if collector is not None:
-                collector.validate(ci, t=now, tainted=tainted)
-            note_validated(ci, now)
-
-        def sync_validation():
-            """Validate every chunk that has left the flight queue —
-            normal retirements AND remesh-barrier drains."""
-            while len(pending_val) > len(self._in_flight):
-                ci, out, t_launch, M, L, fin, compiled = pending_val.popleft()
-                validate(ci, out, t_launch, M, L, fin, compiled)
-
-        def launch(ci: int) -> bool:
-            """Stage + compile + dispatch chunk ``ci``.  Returns False when
-            a fault hook failed the launch (the chunk was requeued, or a
-            member recovery already re-queued the replay set)."""
-            lo, hi = ci * chunk, min((ci + 1) * chunk, B)
-            n_live = hi - lo
-            M = self.executor.n_members
-            L = pad_to_shards(chunk, M)
-            if injector is not None:
-                try:
-                    injector.on_launch(ci, self.executor.device_list)
-                except MemberFailedError as e:
-                    # the MEMBER failed, not the chunk: no attempt consumed
-                    report.failures.append(
-                        {"chunk": ci, "kind": "member_crash",
-                         "attempt": attempts[ci], "member": e.member,
-                         "detail": str(e), "wall_s": None})
-                    if journal is not None:
-                        journal.append(
-                            {"type": "fault", "chunk": int(ci),
-                             "kind": "member_crash", "member": e.member,
-                             "detail": str(e)})
-                    recover_member(e.device, e.member, ci,
-                                   cause="member crash detected at launch")
-                    return False
-            with span("dispatch.stage", collector, stream=sid, chunk=ci):
-                in_place = on_device and self._reads_in_place(job, src)
-                if in_place:
-                    # the executable cuts rows [lo, lo + L) itself
-                    args = (src, lo, n_live)
-                    report.staged_in_place += 1
-                elif on_device:
-                    args = self.executor.slice_chunk(src, lo, L, n_live)
-                else:
-                    args = self._stage_host(items_np, lo, n_live, L)
-                    report.staged_host += 1
-                report.staged_device += on_device
-            with span("dispatch.launch", collector, stream=sid,
-                      chunk=ci) as launching:
-                builds_before = self.cache.builds
-                try:
-                    if injector is not None:
-                        injector.on_compile(ci)
-                    fn = self._executable(job, args[0], replicated, L,
-                                          in_place)
-                except CompileFailedError as e:
-                    fail_chunk(ci, "compile_fail", detail=str(e))
-                    return False
-                compiled_now = self.cache.builds != builds_before
-                launching.annotate(built=int(compiled_now))
-                t_launch = time.perf_counter()
-                launch_epoch[ci] = self._epoch
-                if collector is not None:
-                    collector.dispatch(ci, t_launch, tainted=compiled_now)
-                out = fn(*args, *replicated)             # async dispatch
-            # (deterministic jobs: the executable itself tree-reduced
-            # the rows, so `out` is already the chunk partial)
-            if injector is not None:
-                out = injector.maybe_poison(ci, out, L, M)
-            if depth == 0:
-                # synchronous baseline (``streamed_sync``): materialize
-                # the chunk on host NOW — one blocking D2H per chunk,
-                # exactly the pre-async behavior this pipeline replaces
-                with span("dispatch.retire", collector, stream=sid,
-                          chunk=ci):
-                    out = jax.tree_util.tree_map(np.asarray, out)
-                if collector is not None:
-                    collector.retire(ci, tainted=compiled_now)
-                    if not guarded:
-                        collector.validate(ci, tainted=compiled_now)
-                mark(compiled_now, t_launch)
-            else:
-                self._in_flight.append((ci, out, compiled_now, t_launch))
-                report.max_in_flight = max(report.max_in_flight,
-                                           len(self._in_flight))
-            # combine lazily, in chunk order — retirement (blocking) is
-            # decoupled from reduction, so order never depends on how
-            # many chunks are in flight.  concat rows are trimmed at the
-            # reduce boundary, not here: an eager mid-stream slice of an
-            # unevenly-sharded chunk would cost a per-chunk reshard
-            parts[ci] = (n_live, out)
-            if journal is not None and not guarded:
-                unjournaled.add(ci)
-            part_epochs.add(self._epoch)
-            report.members_per_chunk.append(M)
-            if guarded:
-                if depth == 0:
-                    # sync baseline: out is already host numpy — the cheap
-                    # np fallback inside validate covers it
-                    validate(ci, out, t_launch, M, L, compiled=compiled_now)
-                else:
-                    fin = (_finite_probe(out)
-                           if policy.check_finite or injector is not None
-                           else None)
-                    pending_val.append(
-                        (ci, out, t_launch, M, L, fin, compiled_now))
-            if depth == 0 and not guarded:
-                journal_chunk(ci)        # sync baseline: launch is final
-            return True
-
-        t_start = time.perf_counter()
-        try:
-            while queue:
-                journal_settled()        # barrier-drained chunks are final
-                if journal is not None and self._drain_requested.is_set():
-                    drain_now()          # raises DrainInterrupted
-                ci = queue.popleft()
-                if not launch(ci):
-                    continue
-                if on_chunk is not None and ci not in fired_cb:
-                    # scale schedules stay deterministic under faults: the
-                    # callback fires once per chunk INDEX, on its first
-                    # launch, never again on replays
-                    fired_cb.add(ci)
-                    on_chunk(self, ci, n_chunks)
-                    if guarded:
-                        sync_validation()   # an on_chunk remesh drained
-                while len(self._in_flight) > depth:
-                    retire_oldest()
-                if queue:
-                    continue
-                # tail of the stream (validation failures may refill queue)
-                # (a collector must also block-retire the tail: lazy drop
-                # would leave its last chunks' retire/validate un-stamped;
-                # a journaled stream must retire every chunk through
-                # journal_chunk, so it never lazy-drops either)
-                if (guarded or collector is not None or journal is not None
-                        or (self.auto_scale and on_chunk is None)):
-                    # the IAS needs samples even from streams shorter than
-                    # the pipeline depth, and the guarded path must block
-                    # to validate: drain the tail WITH sampling (short
-                    # streams fall back to launch-to-completion walls)
-                    while self._in_flight and not queue:
-                        retire_oldest()
-                    if guarded and not queue:
-                        sync_validation()
-                else:
-                    # lazy delivery: drop the queue without blocking —
-                    # `parts` keeps the arrays alive, the in-flight bound
-                    # was enforced chunk by chunk, and the caller blocks at
-                    # its own reduce boundary (host delivery materializes
-                    # right below anyway)
-                    self._in_flight.clear()
-        except DrainInterrupted:
-            raise                        # graceful preemption, not a dying
-            # stream: the journal is closed, calibration stays valid
-        except Exception as exc:
-            # durable post-mortem: a JobFailedError's structured report is
-            # journaled BEFORE raising (it would otherwise die with the
-            # coordinator); other exceptions leave an aborted marker.  Best
-            # effort — a failing journal must not mask the real error.
-            if journal is not None:
-                try:
-                    if isinstance(exc, JobFailedError):
-                        journal.append(
-                            {"type": "job_failed", "message": str(exc),
-                             "report": exc.report.summary()}, fsync=True)
-                    else:
-                        journal.append({"type": "aborted",
-                                        "error": repr(exc)}, fsync=True)
-                    journal.close()
-                except Exception:
-                    pass
-            # a dying stream must not poison the job class's IAS
-            # calibration: its compile/retry-inflated first sample would
-            # steer the NEXT stream's scaler (explicit calibrate_target
-            # pins survive — the operator asserted those)
-            if job.signature not in self._explicit_targets:
-                self.job_targets.pop(job.signature, None)
-            raise
-        finally:
-            # exception mid-stream (a failing on_chunk, a bad replicated
-            # operand, an unrecoverable fault): quiesce and forget every
-            # launched chunk so the dispatcher is reusable and no buffer
-            # outlives the stream
-            self._drain_in_flight()
-
-        # one geometry throughout, an async stream, and device delivery:
-        # combine on device and expose the result lazily; host delivery, a
-        # mid-stream remesh (parts on different device sets), a resumed
-        # stream (the restored base lives in host memory) or the
-        # synchronous baseline (parts already np, legacy host-output
-        # semantics) combine on host
-        combine_on_device = (deliver == "device" and depth > 0
-                             and len(part_epochs) <= 1 and _resume is None)
-        resume_base = None if _resume is None else _resume["base_state"]
-        with span("dispatch.combine", collector, stream=sid):
-            outputs = self._combine(job, parts[base_k:], combine_on_device,
-                                    base=resume_base)
-        if journal is not None:
-            # completion is durable too: journal any straggler chunks and
-            # tail scale events, persist the combined output as the FINAL
-            # checkpoint, mark the stream complete (fsync'd) — resuming a
-            # complete journal then returns this state with zero executions
-            journal_settled()
-            journal_scales()
-            host_out = jax.tree_util.tree_map(np.asarray, outputs)
-            journal.write_checkpoint(n_chunks, "final", host_out,
-                                     {"n_members": self.n_members})
-            journal.append({"type": "complete", "n_chunks": n_chunks},
-                           fsync=True)
-            journal.wait()
-            report.checkpoints = journal.n_checkpoints
-            report.checkpoint_write_s = list(journal.write_s)
-            if collector is not None:
-                for w_s in journal.write_s:
-                    collector.record_checkpoint(w_s)
-            journal.close()
-            self._drain_requested.clear()  # a drain that lost the race to
-            # completion must not preempt the NEXT stream
-        report.compiles = self.cache.builds - builds0
-        report.cache_hits = self.cache.hits - hits0
-        report.scale_events = len(self.scale_events) - events0
-        report.wall_s = time.perf_counter() - t_start
-        return outputs, report
+    def _chunk_plan(self, items, chunk: Optional[int]):
+        """``(leaves, B, chunk, n_chunks)`` of a stream over ``items``.
+        B == 0 still runs ONE fully-padded chunk (valid all-False): concat
+        outputs trim to correct empty arrays, sum/max partials reduce over
+        masked-out rows only — parity with the non-dispatcher vmap path."""
+        leaves = jax.tree_util.tree_leaves(items)
+        if not leaves:
+            raise ValueError("a stream needs at least one item array")
+        B = int(leaves[0].shape[0])
+        if any(int(l.shape[0]) != B for l in leaves):
+            raise ValueError("item arrays must share their leading dim")
+        chunk = chunk if chunk is not None else (self.chunk_size or B)
+        chunk = max(1, min(int(chunk), max(B, 1)))
+        return leaves, B, chunk, max(-(-B // chunk), 1)
 
     # ---------------------------------------------------- staging + combine
     def _pad_device_source(self, items, chunk: int, n_chunks: int, B: int):
@@ -1820,9 +1112,9 @@ class ElasticDispatcher:
         trimmed HERE, off the hot loop.  On ONE geometry (no mid-stream
         remesh) an async stream stays on device and the result is exposed
         lazily; across geometries the parts live on different device sets
-        (eager device ops would not colocate) and the synchronous baseline
-        already materialized per chunk, so those combine on host — the
-        IEEE-754 f32 ops are bitwise identical either way.
+        (eager device ops would not colocate), so those combine on host, as
+        depth 0 does — the IEEE-754 f32 ops are bitwise identical either
+        way.
 
         ``base`` is a resumed stream's restored checkpoint state (chunks
         before the checkpoint never re-ran): the concatenated row prefix
@@ -2007,3 +1299,669 @@ class ElasticDispatcher:
             return out
 
         return call
+
+
+# ------------------------------------------------------------ the chunk stream
+
+class _Stream:
+    """One ``submit``'s chunk stream and all of its state.
+
+    Every chunk walks one path: launched (``stage`` + ``launch``) → retired
+    (``retire_oldest``, or a remesh barrier's drain) → validated
+    (``sync_validation``) → final (``note_validated``, which journals it).
+    What the stream arms decides only how much validation does: with an
+    active ``RetryPolicy`` or a ``FaultInjector`` it reads the finiteness
+    probe enqueued at launch, checks the deadline, feeds the dispatcher's
+    fault monitor, and turns a detected failure into a replay; unarmed, it
+    stamps the collector and marks the chunk final.  ``depth`` bounds the
+    flight queue: at 0 the loop retires each chunk right after its launch.
+
+    The dispatcher holds the active stream: ``ElasticDispatcher.in_flight``
+    and the remesh barrier's ``_drain_in_flight`` read its flight queue."""
+
+    def __init__(self, d: ElasticDispatcher, job: DispatchJob, items, leaves,
+                 B: int, chunk: int, n_chunks: int,
+                 collector: Optional[DispatchStats], sid: int, *,
+                 replicated, on_chunk, dispatch_ahead, deliver, retry_policy,
+                 fault_injector, checkpoint, resume, tenant):
+        self.d, self.job, self.replicated = d, job, replicated
+        self.B, self.chunk, self.n_chunks = B, chunk, n_chunks
+        self.collector, self.sid = collector, sid
+        self.on_chunk, self.deliver, self.resume = on_chunk, deliver, resume
+        self.depth = (d.dispatch_ahead if dispatch_ahead is None
+                      else max(int(dispatch_ahead), 0))
+        # device-side cuts save the host round-trip once the copies matter;
+        # tiny item sets (a grid's per-variant scalars) and depth 0 (the
+        # synchronous reference runs) stage through host numpy
+        n_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
+        self.on_device = (self.depth > 0 and B > 0
+                          and n_bytes >= d.device_slice_min_bytes
+                          and all(isinstance(l, jax.Array) for l in leaves))
+        self.src = (d._pad_device_source(items, chunk, n_chunks, B)
+                    if self.on_device
+                    else jax.tree_util.tree_map(np.asarray, items))
+
+        policy = retry_policy if retry_policy is not None else d.retry_policy
+        self.injector = fault_injector   # submit resolved the default
+        if policy is None:
+            # an injector without an explicit policy still needs a detector:
+            # default attempt budget with the finiteness probe armed
+            policy = RetryPolicy(check_finite=self.injector is not None)
+        self.policy = policy
+        self.armed = self.injector is not None or policy.active
+        self.probe = policy.check_finite or self.injector is not None
+        if job.deterministic and n_chunks > 1 and chunk & (chunk - 1) != 0:
+            warnings.warn(
+                f"deterministic float sum chunked at {chunk} (not a power of"
+                " two): results are deterministic and replay-stable for THIS"
+                " chunking but not bit-identical across chunk sizes — use a"
+                " power-of-two chunk for the cross-chunking guarantee",
+                NonPow2ChunkWarning, stacklevel=2)
+
+        self.report = DispatchReport(
+            job=job.name, n_items=B, chunk=chunk, n_chunks=n_chunks,
+            dispatch_ahead=self.depth, kernel_path=job.kernel_path,
+            map_path=d._map_path(job), tenant=tenant)
+        self.hits0, self.builds0 = d.cache.hits, d.cache.builds
+        self.events0 = len(d.scale_events)
+        self.base_k = 0 if resume is None else int(resume["base_k"])
+        self.open_journal(
+            checkpoint if checkpoint is not None else d.checkpoint_policy,
+            items)
+        # (n_live, output) by chunk index: a REPLAY overwrites its slot and
+        # the combine walks slots in index order, so retries never perturb
+        # the reduce tree; a resumed stream fills only slots >= base_k
+        self.parts: List[Optional[Tuple[int, object]]] = [None] * n_chunks
+        self.part_epochs: set = set()    # geometries the parts live on
+        self.queue: Deque[int] = collections.deque(range(self.base_k,
+                                                         n_chunks))
+        # launched, not retired: (chunk, out, compiled, t_launch)
+        self.flight: Deque[Tuple] = collections.deque()
+        # left the flight queue, not validated: the flight queue is always
+        # its suffix; what lies before it a retirement or a barrier drained
+        self.pending_val: Deque[Tuple] = collections.deque()
+        self.launch_epoch: Dict[int, int] = {}  # chunk -> epoch at launch
+        self.fired_cb: set = set()       # chunks whose on_chunk has run
+        self.attempts: Dict[int, int] = collections.Counter()
+        self.strikes: Dict = collections.Counter()  # retryable fails/device
+        self.open_recoveries: List[dict] = []  # awaiting their replays
+        self.fail_t: Dict[int, float] = {}  # chunk -> last failure detected
+        self.val_step = 0                # fault-monitor sample index
+        # the step-time sampler (``mark``)
+        self.alpha = getattr(d.health_cfg, "ema_alpha", 0.4)
+        self.t_mark: Optional[float] = None
+        self.ema: Optional[float] = None
+        self.epoch = d._epoch
+        # nothing after retirement needs the chunks one by one (no checks,
+        # stamps, journal or auto-scale samples): ``settle_tail`` drops them
+        self.lazy_tail = not (self.armed or collector is not None
+                              or self.journal is not None
+                              or (d.auto_scale and on_chunk is None))
+        if collector is not None:
+            # a submit stream is a CLOSED arrival process: every chunk is
+            # ready at stream start, so they share one enqueue stamp and
+            # queue_wait measures time spent behind the pipeline bound
+            t0_enq = collector.clock()
+            for ci in self.queue:
+                collector.enqueue(ci, t0_enq)
+
+    # ------------------------------------------------------------ durability
+    def open_journal(self, ckpolicy: Optional[CheckpointPolicy], items):
+        """Open the stream's journal (or adopt a resume's) and its durable
+        reduce state: ``ck_k`` the folded prefix length, ``ck_state`` the
+        binary-counter pending dict (sum/max) or the concatenated prefix
+        (concat), ``ck_host`` validated host copies awaiting the next fold,
+        ``journaled`` the chunks recorded, ``digests`` the journaled
+        digests a resumed run re-verifies its replays against."""
+        d, report, resume = self.d, self.report, self.resume
+        self.ckpolicy = ckpolicy
+        self.journal: Optional[JobJournal] = None
+        self.ck_k, self.ck_state = self.base_k, None
+        self.ck_host: Dict[int, object] = {}
+        self.journaled: set = set()
+        self.digests: Dict[int, str] = {}
+        self.n_scale = 0                 # scale events journaled
+        if ckpolicy is None:
+            return
+        if resume is not None:
+            self.journal = resume["journal"]
+            self.ck_state = resume["base_state"]
+            self.digests = dict(resume["digests"])
+            report.resumed_from = resume["path"]
+            report.chunks_skipped = self.base_k
+            report.chunks_replayed = self.n_chunks - self.base_k
+        else:
+            env = d._env_signature(self.job, self.B, self.chunk,
+                                   self.n_chunks, items, self.replicated)
+            self.journal = JobJournal.create(ckpolicy, {
+                "env": env, "n_members": d.n_members,
+                "owner": d.table.owner.tolist(),
+                "every_n_chunks": ckpolicy.every_n_chunks})
+        report.journal_path = self.journal.path
+
+    def journal_scales(self):
+        """Journal scale events fired since the last call, each with the
+        post-event member count and partition-owner snapshot — what
+        ``resume`` rebuilds the topology from."""
+        d = self.d
+        while self.journal is not None and \
+                self.n_scale < len(d.scale_events) - self.events0:
+            ev = d.scale_events[self.events0 + self.n_scale]
+            self.journal.append({"type": "scale", "event": ev,
+                                 "n_members": d.n_members,
+                                 "owner": d.table.owner.tolist()})
+            self.n_scale += 1
+
+    def advance_checkpoint(self, force: bool = False):
+        """Fold newly-contiguous validated chunks into the durable reduce
+        state and persist it (atomic dir) when a stride boundary is crossed
+        — or at the exact watermark when a drain forces it.  The counter
+        state after ANY validated prefix k is exactly the pow2 subtrees of
+        k's binary decomposition, so resume is bit-identical.  Runs on the
+        journal WRITER thread; ``drain_now`` calls it only after
+        ``journal.wait`` has idled that thread."""
+        w = self.ck_k
+        while w in self.ck_host:
+            w += 1
+        stride = self.ckpolicy.every_n_chunks
+        boundary = w if force else (w // stride) * stride
+        if boundary <= self.ck_k or (not force and boundary >= self.n_chunks):
+            return                       # completion writes the final state
+        if self.job.reduce == "concat":
+            pieces = [] if self.ck_state is None else [self.ck_state]
+            pieces += [self.ck_host.pop(ci)
+                       for ci in range(self.ck_k, boundary)]
+            self.ck_state = jax.tree_util.tree_map(
+                lambda *xs: np.concatenate(xs, axis=0), *pieces)
+            kind = "prefix"
+        else:
+            combine = np.add if self.job.reduce == "sum" else np.maximum
+            # shallow-copy so a resume's restored base dict is never
+            # mutated — the final combine still needs it untouched
+            pending = dict(self.ck_state or {})
+            for ci in range(self.ck_k, boundary):
+                counter_push(pending, self.ck_host.pop(ci), combine)
+            self.ck_state = pending
+            kind = "pending"
+        self.ck_k = boundary
+        self.journal.checkpoint_now(boundary, kind, self.ck_state,
+                                    {"n_members": self.d.n_members})
+
+    def host_part(self, out, n_live: int):
+        """A chunk's output gathered to host, concat rows trimmed."""
+        host = jax.tree_util.tree_map(np.asarray, out)
+        if self.job.reduce == "concat":
+            host = jax.tree_util.tree_map(lambda a: a[:n_live], host)
+        return host
+
+    def finish_chunk(self, ci: int, out, n_live: int, record: dict):
+        """Writer-thread tail of ``journal_chunk``: gather, digest, write
+        the chunk record, stage the host copy for the fold, advance the
+        checkpoint.  Everything here walks output bytes, so the dispatch
+        thread pays one queue put per chunk."""
+        host = self.host_part(out, n_live)
+        if "digest" not in record:
+            record["digest"] = tree_digest(host)
+        self.journal.sync_append(record)
+        self.ck_host[ci] = host
+        self.advance_checkpoint()
+
+    def journal_chunk(self, ci: int):
+        """Close the durable books on one FINAL chunk.  The heavy tail —
+        host gather, digest, fold, checkpoint — rides the journal writer
+        thread via ``defer``; only a resumed replay digests HERE, inline,
+        because a divergent replay must stop the stream immediately, not
+        surface after more chunks launched."""
+        if self.journal is None or ci in self.journaled or ci < self.base_k:
+            return
+        n_live, out = self.parts[ci]
+        record = {"type": "chunk", "chunk": int(ci),
+                  "attempt": int(self.attempts[ci]), "n_live": int(n_live)}
+        expect = self.digests.get(ci)
+        if expect is not None:
+            host = self.host_part(out, n_live)
+            digest = tree_digest(host)
+            if digest != expect:
+                raise ResumeMismatchError(
+                    f"replayed chunk {ci} digest {digest[:12]}… does "
+                    f"not match the journaled {expect[:12]}… — the "
+                    "items or job differ from the journaled stream")
+            record["digest"] = digest
+            out = host                   # gathered once; the fold reuses it
+        elif not self.ckpolicy.digest_chunks:
+            record["digest"] = None
+        self.journal.defer(lambda c=ci, o=out, nl=n_live, r=record:
+                           self.finish_chunk(c, o, nl, r))
+        self.journaled.add(ci)
+        self.journal_scales()
+
+    def close_journal(self):
+        """Settle the writer thread, report the checkpoints, close."""
+        journal, report = self.journal, self.report
+        journal.wait()
+        report.checkpoints = journal.n_checkpoints
+        report.checkpoint_write_s = list(journal.write_s)
+        if self.collector is not None:
+            for w_s in journal.write_s:
+                self.collector.record_checkpoint(w_s)
+        journal.close()
+
+    def drain_now(self):
+        """Graceful preemption (``request_drain``/SIGTERM): stop
+        launching, retire + validate everything in flight, checkpoint
+        the exact validated watermark, journal the drain, and raise
+        ``DrainInterrupted`` — ``resume`` continues the stream later."""
+        self.d._drain_requested.clear()
+        while self.flight:
+            self.retire_oldest()
+        self.sync_validation()
+        self.journal_scales()
+        self.journal.wait()              # settle the deferred fold tails so
+        # ck_k/ck_state are this thread's to touch
+        self.advance_checkpoint(force=True)
+        self.journal.append({"type": "drain", "k": int(self.ck_k),
+                             "remaining": sorted(self.queue)}, fsync=True)
+        self.close_journal()
+        if self.collector is not None:
+            self.report.stats = self.collector.summary(n_servers=1)
+        self.report.wall_s = time.perf_counter() - self.t_start
+        raise DrainInterrupted(
+            f"stream of job {self.job.name!r} drained at validated prefix "
+            f"{self.ck_k}/{self.n_chunks} on request", self.report,
+            self.journal.path)
+
+    # ------------------------------------------------------------ retirement
+    def mark(self, compiled: bool, t_launch: float):
+        """Sample one step time — retirement-to-retirement in steady state,
+        launch-to-completion when nothing retired before (short streams) —
+        and, under auto_scale, feed EMA/target to the IAS.  Compile chunks
+        and remesh barriers reset the timer instead: their wall is compile
+        or rebuild noise that would ratchet the scaler to max_instances."""
+        d = self.d
+        now = time.perf_counter()
+        if compiled or self.epoch != d._epoch:
+            self.epoch = d._epoch
+            self.t_mark = now
+            return
+        since = (t_launch if self.t_mark is None
+                 else max(self.t_mark, t_launch))
+        dt, self.t_mark = now - since, now
+        self.ema = (dt if self.ema is None
+                    else self.alpha * dt + (1.0 - self.alpha) * self.ema)
+        self.report.ema_step_s = self.ema
+        if not (d.auto_scale and self.on_chunk is None):
+            return
+        if d.health_cfg.policy == "mmn" and self.collector is not None:
+            # queue-aware feed: measured per-member service rate vs the
+            # demand anchor 1/target.  Closed streams have no meaningful
+            # arrival process, so queue_length stays 0 — backlog is
+            # pipeline structure, not unmet demand (open callers like
+            # serve/ pass a measured Lq themselves).
+            s = self.collector.mean_service()
+            if math.isfinite(s) and s > 0:
+                target = d._job_target(self.job, s)
+                d.controller.tick_queue(QueueSnapshot(
+                    arrival_rate=1.0 / target,
+                    service_rate=1.0 / (s * d.n_members),
+                    n_members=d.n_members, queue_length=0.0))
+        else:
+            d.observe_load(self.ema / d._job_target(self.job, self.ema))
+
+    def retire_oldest(self):
+        """Block on the oldest launched chunk, sample its step time, then
+        validate every chunk that has left the flight queue."""
+        ci, out, compiled, t_launch = self.flight.popleft()
+        with span("dispatch.retire", self.collector, stream=self.sid,
+                  chunk=ci):
+            jax.block_until_ready(out)
+        if self.collector is not None:
+            self.collector.retire(
+                ci, tainted=compiled or self.launch_epoch.get(ci)
+                != self.d._epoch)
+        self.mark(compiled, t_launch)
+        self.sync_validation()
+
+    def drain_in_flight(self) -> int:
+        """Block until every launched chunk has retired; returns how many
+        were in flight.  If a chunk raises at the blocking point the rest
+        is still dropped: no stale chunk may leak into the next stream."""
+        n = len(self.flight)
+        try:
+            while self.flight:
+                jax.block_until_ready(self.flight.popleft()[1])
+        finally:
+            self.flight.clear()
+        return n
+
+    def sync_validation(self):
+        """Validate every chunk that has left the flight queue — normal
+        retirements AND remesh-barrier drains."""
+        while len(self.pending_val) > len(self.flight):
+            self.validate(*self.pending_val.popleft())
+
+    def validate(self, ci: int, out, t_launch: float, M: int, L: int, fin,
+                 compiled: bool):
+        """Settle one retired chunk: fire any scheduled stall, take the
+        chunk's wall, read the finiteness probe (``fin``, enqueued at
+        launch on armed streams), feed the fault monitor (armed streams
+        only, so its window sees what it always saw), and route a detected
+        failure to ``fail_chunk``; otherwise stamp and mark final."""
+        d, c = self.d, self.collector
+        delay, stall_slot = (self.injector.stall_for(ci)
+                             if self.injector is not None else (0.0, None))
+        if delay > 0:
+            time.sleep(delay)             # the hung launch: retirement late
+        now = time.perf_counter()
+        wall = now - t_launch
+        tainted = compiled or self.launch_epoch.get(ci) != d._epoch
+        finite = fin is None or bool(fin)
+        if self.armed:
+            member_times = None
+            if stall_slot is not None:
+                member_times = [max(wall - delay, 0.0)] * M
+                member_times[stall_slot % M] = wall
+            self.val_step += 1
+            d.fault_monitor.observe_chunk(
+                step=self.val_step, wall_s=wall, finite=finite,
+                member_times=member_times, tainted=tainted)
+        if c is not None and delay > 0:
+            c.record_stall(delay)
+        if not finite:
+            if c is not None:
+                # a failed attempt's wall is fault noise: keep the
+                # record's time integrals, drop it from the windows
+                c.validate(ci, t=now, tainted=True)
+            self.fail_chunk(ci, "nan_poison",
+                            member=_nonfinite_member(out, L, M),
+                            detail="non-finite chunk output", wall=wall)
+            return
+        timeout = self.policy.chunk_timeout_s
+        if timeout is not None and wall > timeout:
+            if c is not None:
+                c.validate(ci, t=now, tainted=True)
+            self.fail_chunk(
+                ci, "stall", member=stall_slot,
+                detail=(f"wall {wall:.3f}s exceeded deadline {timeout}s "
+                        f"(straggler skew "
+                        f"{d.fault_monitor.straggler_skew():.2f})"),
+                wall=wall)
+            return
+        if c is not None:
+            c.validate(ci, t=now, tainted=tainted)
+        self.note_validated(ci, now)
+
+    def note_validated(self, ci: int, now: float):
+        """Mark a validated chunk final: stamp the recovery latency on its
+        latest failure record and on any open member-failure recovery
+        awaiting its replay, and journal it."""
+        t0 = self.fail_t.pop(ci, None)
+        if t0 is not None:
+            for rec in reversed(self.report.failures):
+                if rec["chunk"] == ci and "recovered_after_s" not in rec:
+                    rec["recovered_after_s"] = now - t0
+                    break
+        for open_rec in self.open_recoveries[:]:
+            open_rec["outstanding"].discard(ci)
+            if not open_rec["outstanding"]:
+                open_rec["event"]["recovery_s"] = now - open_rec["t0"]
+                self.open_recoveries.remove(open_rec)
+        self.journal_chunk(ci)
+
+    # --------------------------------------------------------------- failure
+    def record_failure(self, ci: int, kind: str, member, detail: str,
+                       wall=None):
+        """One detected failure: in the report, and in the journal (retry
+        and fault events are durable too)."""
+        rec = {"chunk": ci, "kind": kind, "attempt": self.attempts[ci],
+               "member": member, "detail": detail}
+        self.report.failures.append(dict(rec, wall_s=wall))
+        if self.journal is not None:
+            self.journal.append(dict(type="fault", **rec))
+
+    def requeue(self, ci: int):
+        self.queue.appendleft(ci)
+        if self.collector is not None:
+            self.collector.enqueue(ci)
+
+    def recover_member(self, device, slot: int, failed_ci: int, cause: str):
+        """Member-failure recovery: the replay set is the failed chunk
+        plus every launched-but-unvalidated chunk (their buffers may
+        live on the dead member); drain the survivors, force the
+        failure remesh, and requeue the replays in ascending order."""
+        t0 = time.perf_counter()
+        lost = sorted({failed_ci} | {e[0] for e in self.pending_val}
+                      | {e[0] for e in self.flight})
+        self.drain_in_flight()
+        self.pending_val.clear()
+        self.strikes.pop(device, None)
+        event = self.d._member_failure_remesh(device, slot, self.report)
+        event.update({"cause": cause, "dead_member": slot,
+                      "dead_device": str(device), "failed_chunk": failed_ci,
+                      "replayed_chunks": lost})
+        self.report.recovery_events.append(event)
+        self.report.retries += len(lost)
+        self.open_recoveries.append(
+            {"event": event, "t0": t0, "outstanding": set(lost)})
+        for ci in reversed(lost):
+            self.requeue(ci)
+
+    def fail_chunk(self, ci: int, kind: str, member=None, detail: str = "",
+                   wall=None):
+        """Record one retryable chunk failure, enforce the attempt
+        budget, quarantine a repeat-offender member, back off, and
+        requeue the chunk for replay."""
+        d, policy, report = self.d, self.policy, self.report
+        self.attempts[ci] += 1
+        attempt = self.attempts[ci]
+        self.fail_t[ci] = time.perf_counter()
+        self.record_failure(ci, kind, member, detail, wall)
+        if attempt >= policy.max_attempts:
+            raise JobFailedError(
+                f"chunk {ci} of job {self.job.name!r} failed {attempt}x"
+                f" (last: {kind}); attempts exhausted (max_attempts="
+                f"{policy.max_attempts})", report)
+        if member is not None and policy.quarantine_after > 0:
+            mesh_devices = d.executor.device_list
+            dev = mesh_devices[member % len(mesh_devices)]
+            self.strikes[dev] += 1
+            # quarantine only when the pool can afford to lose the
+            # member; otherwise keep retrying under the attempt budget
+            can_drop = len(d.devices) - 1 >= max(1,
+                                                 d.health_cfg.min_instances)
+            if self.strikes[dev] >= policy.quarantine_after and can_drop:
+                self.recover_member(
+                    dev, member, ci,
+                    cause=(f"quarantined: {self.strikes[dev]} retryable "
+                           f"failures attributed to one member "
+                           f"(last: {kind})"))
+                return
+        report.retries += 1
+        backoff = policy.backoff_for(attempt)
+        if backoff > 0:
+            time.sleep(backoff)
+        self.requeue(ci)
+
+    # ---------------------------------------------------------------- launch
+    def stage(self, ci: int, lo: int, n_live: int, L: int):
+        """Cut chunk ``ci``'s rows ``[lo, lo + L)``: in place by the job's
+        own executable (one member holding the device source), by
+        ``executor.slice_chunk`` (other device sources), or by host numpy.
+        Returns the executable's leading arguments and whether they are
+        the whole source."""
+        d, report = self.d, self.report
+        with span("dispatch.stage", self.collector, stream=self.sid,
+                  chunk=ci):
+            in_place = self.on_device and d._reads_in_place(self.job,
+                                                            self.src)
+            if in_place:
+                # the executable cuts rows [lo, lo + L) itself
+                args = (self.src, lo, n_live)
+                report.staged_in_place += 1
+            elif self.on_device:
+                args = d.executor.slice_chunk(self.src, lo, L, n_live)
+            else:
+                args = d._stage_host(self.src, lo, n_live, L)
+                report.staged_host += 1
+            report.staged_device += self.on_device
+        return args, in_place
+
+    def launch(self, ci: int) -> bool:
+        """Stage + compile + dispatch chunk ``ci`` into the flight queue.
+        Returns False when a fault hook failed the launch (the chunk was
+        requeued, or a member recovery already re-queued the replay set)."""
+        d, report, injector = self.d, self.report, self.injector
+        lo, hi = ci * self.chunk, min((ci + 1) * self.chunk, self.B)
+        n_live = hi - lo
+        M = d.executor.n_members
+        L = pad_to_shards(self.chunk, M)
+        if injector is not None:
+            try:
+                injector.on_launch(ci, d.executor.device_list)
+            except MemberFailedError as e:
+                # the MEMBER failed, not the chunk: no attempt consumed
+                self.record_failure(ci, "member_crash", e.member, str(e))
+                self.recover_member(e.device, e.member, ci,
+                                    cause="member crash detected at launch")
+                return False
+        args, in_place = self.stage(ci, lo, n_live, L)
+        with span("dispatch.launch", self.collector, stream=self.sid,
+                  chunk=ci) as launching:
+            builds_before = d.cache.builds
+            try:
+                if injector is not None:
+                    injector.on_compile(ci)
+                fn = d._executable(self.job, args[0], self.replicated, L,
+                                   in_place)
+            except CompileFailedError as e:
+                self.fail_chunk(ci, "compile_fail", detail=str(e))
+                return False
+            compiled = d.cache.builds != builds_before
+            launching.annotate(built=int(compiled))
+            t_launch = time.perf_counter()
+            self.launch_epoch[ci] = d._epoch
+            if self.collector is not None:
+                self.collector.dispatch(ci, t_launch, tainted=compiled)
+            out = fn(*args, *self.replicated)             # async dispatch
+        # (deterministic jobs: the executable itself tree-reduced the rows,
+        # so `out` is already the chunk partial)
+        if injector is not None:
+            out = injector.maybe_poison(ci, out, L, M)
+        self.flight.append((ci, out, compiled, t_launch))
+        report.max_in_flight = max(report.max_in_flight, len(self.flight))
+        # combine lazily, in chunk order — retirement (blocking) is
+        # decoupled from reduction, so order never depends on how many
+        # chunks are in flight.  concat rows are trimmed at the reduce
+        # boundary, not here: an eager mid-stream slice of an unevenly-
+        # sharded chunk would cost a per-chunk reshard
+        self.parts[ci] = (n_live, out)
+        self.part_epochs.add(d._epoch)
+        report.members_per_chunk.append(M)
+        fin = _finite_probe(out) if self.probe else None
+        self.pending_val.append((ci, out, t_launch, M, L, fin, compiled))
+        return True
+
+    # ------------------------------------------------------------ the stream
+    def run(self) -> Tuple[object, DispatchReport]:
+        """Launch every queued chunk, keeping at most ``depth`` in flight,
+        then combine and close the books."""
+        d, job = self.d, self.job
+        self.t_start = time.perf_counter()
+        try:
+            while self.queue:
+                if self.journal is not None and d._drain_requested.is_set():
+                    self.drain_now()     # raises DrainInterrupted
+                ci = self.queue.popleft()
+                if not self.launch(ci):
+                    continue
+                while len(self.flight) > self.depth:
+                    self.retire_oldest()
+                if self.on_chunk is not None and ci not in self.fired_cb:
+                    # scale schedules stay deterministic under faults: the
+                    # callback fires once per chunk INDEX, on its first
+                    # launch, never again on replays
+                    self.fired_cb.add(ci)
+                    self.on_chunk(d, ci, self.n_chunks)
+                    self.sync_validation()   # an on_chunk remesh drained
+                if not self.queue:
+                    self.settle_tail()   # validation may refill the queue
+        except DrainInterrupted:
+            raise                        # graceful preemption, not a dying
+            # stream: the journal is closed, calibration stays valid
+        except Exception as exc:
+            self.abort(exc)
+            raise
+        finally:
+            # quiesce and forget every launched chunk, so after an
+            # exception mid-stream (a failing on_chunk, a bad replicated
+            # operand, an unrecoverable fault) the dispatcher is reusable
+            # and no buffer outlives the stream
+            self.drain_in_flight()
+        return self.finish()
+
+    def settle_tail(self):
+        """The end of the queue.  A lazy tail drops the flight queue without
+        blocking (``parts`` keeps the arrays alive; the caller blocks at
+        its own reduce boundary); any other stream retires, samples and
+        validates every chunk, short streams included."""
+        if self.lazy_tail:
+            self.flight.clear()
+            self.pending_val.clear()
+            return
+        while self.flight and not self.queue:
+            self.retire_oldest()
+        if not self.queue:
+            self.sync_validation()
+
+    def abort(self, exc: Exception):
+        """A dying stream's post-mortem: journal a ``JobFailedError``'s
+        report (or an aborted marker) before the error propagates — best
+        effort, never masking it — and drop the job class's self-calibrated
+        IAS target, whose inflated first sample would steer the NEXT
+        stream's scaler (explicit ``calibrate_target`` pins survive)."""
+        if self.journal is not None:
+            try:
+                if isinstance(exc, JobFailedError):
+                    self.journal.append(
+                        {"type": "job_failed", "message": str(exc),
+                         "report": exc.report.summary()}, fsync=True)
+                else:
+                    self.journal.append({"type": "aborted",
+                                         "error": repr(exc)}, fsync=True)
+                self.journal.close()
+            except Exception:
+                pass
+        if self.job.signature not in self.d._explicit_targets:
+            self.d.job_targets.pop(self.job.signature, None)
+
+    def finish(self) -> Tuple[object, DispatchReport]:
+        """Combine the parts at the reduce boundary; a journaled stream
+        then writes the combined output as its FINAL checkpoint and marks
+        itself complete (fsync'd), so resuming it returns this state with
+        zero executions."""
+        d, report = self.d, self.report
+        # one geometry throughout, a pipelined stream, and device delivery:
+        # combine on device and expose the result lazily; host delivery, a
+        # mid-stream remesh (parts on different device sets), a resumed
+        # stream (the restored base lives in host memory) or depth 0 (the
+        # synchronous reference's host combine) combine on host
+        on_device = (self.deliver == "device" and self.depth > 0
+                     and len(self.part_epochs) <= 1 and self.resume is None)
+        base = None if self.resume is None else self.resume["base_state"]
+        with span("dispatch.combine", self.collector, stream=self.sid):
+            outputs = d._combine(self.job, self.parts[self.base_k:],
+                                 on_device, base=base)
+        if self.journal is not None:
+            self.journal_scales()
+            host_out = jax.tree_util.tree_map(np.asarray, outputs)
+            self.journal.write_checkpoint(self.n_chunks, "final", host_out,
+                                          {"n_members": d.n_members})
+            self.journal.append({"type": "complete",
+                                 "n_chunks": self.n_chunks}, fsync=True)
+            self.close_journal()
+            d._drain_requested.clear()   # a drain that lost the race to
+            # completion must not preempt the NEXT stream
+        report.compiles = d.cache.builds - self.builds0
+        report.cache_hits = d.cache.hits - self.hits0
+        report.scale_events = len(d.scale_events) - self.events0
+        report.wall_s = time.perf_counter() - self.t_start
+        return outputs, report

@@ -151,9 +151,10 @@ class RetryPolicy:
 
     @property
     def active(self) -> bool:
-        """True when the policy asks for per-chunk validation (a deadline or
-        a finiteness check) — the dispatcher then retires every chunk
-        through the guarded path instead of the lazy clear."""
+        """True when the policy asks for per-chunk checks (a deadline or a
+        finiteness check) — the dispatcher then arms every chunk's
+        validation and blocks on the stream's tail instead of dropping
+        it."""
         return self.chunk_timeout_s is not None or self.check_finite
 
     def backoff_for(self, attempt: int) -> float:

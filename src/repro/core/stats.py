@@ -24,9 +24,10 @@ lives here.  Three layers:
                                   async dispatch call issued)
                         retire    chunk's device computation completed
                                   (``block_until_ready`` returned)
-                        validate  guarded validation finished (== retire on
-                                  the unguarded path); the reduce boundary
-                                  closes the stream
+                        validate  validation finished (probe and deadline
+                                  on an armed stream, right after retire
+                                  otherwise); the reduce boundary closes
+                                  the stream
 
                       and turns into decomposed latencies (queue wait vs
                       service vs validation), arrival/throughput rates,

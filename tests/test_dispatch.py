@@ -492,6 +492,28 @@ def test_in_flight_drains_cleanly_on_exception():
     assert d.in_flight == 0
 
 
+def test_unarmed_stream_drops_its_tail_without_blocking(monkeypatch):
+    """The lazy tail: a stream with nothing armed, no stats, no journal
+    and no auto-scale sampling blocks only to hold the flight bound —
+    ``n_chunks - dispatch_ahead`` retirements — and drops the rest of its
+    flight queue, so the combine queues behind the last chunk."""
+    import jax
+
+    d = ElasticDispatcher(start_members=1, dispatch_ahead=2)
+    job = DispatchJob(name="j", signature="j-lazy",
+                      member_fn=lambda x, v, *_: x * 2.0, reduce="concat")
+    x = np.arange(16, dtype=np.float32).reshape(8, 2)
+    d.submit(job, x, chunk=1)                    # compile outside the count
+    blocks = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda t: blocks.append(1) or real(t))
+    out, rep = d.submit(job, x, chunk=1)
+    assert len(blocks) == rep.n_chunks - rep.dispatch_ahead == 6
+    assert d.in_flight == 0 and rep.max_in_flight == 3
+    assert np.asarray(out).tobytes() == (x * 2.0).tobytes()
+
+
 def test_device_resident_items_zero_host_copies(monkeypatch):
     """Device-resident item sets stay on device: chunks are cut by
     ``executor.slice_chunk`` (host staging is patched to FAIL), outputs are

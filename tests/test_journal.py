@@ -359,6 +359,79 @@ def test_drain_request_checkpoints_and_resumes(tmp_path):
     assert rep.chunks_skipped >= 1
 
 
+def test_unarmed_stream_journals_barrier_drained_chunks_once(tmp_path):
+    """An unarmed journaled stream (no policy, no injector) pipelined two
+    deep, with an ``on_chunk`` scale event whose remesh barrier drains
+    chunks past the normal retirement: every chunk is journaled exactly
+    once, and resuming a truncated copy of the journal reproduces the
+    uninterrupted bytes."""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=SRC)
+    r = subprocess.run([sys.executable, "-c", """
+import collections, os, shutil, sys
+import numpy as np, jax
+from repro.core.dispatch import DispatchJob, ElasticDispatcher
+from repro.core.health import HealthConfig
+from repro.core.journal import CheckpointPolicy, load_journal
+
+workdir = sys.argv[1]
+job = DispatchJob(name="affine", signature="affine-journal",
+                  member_fn=lambda x, v, w: x * w + 1.0, reduce="concat")
+rng = np.random.RandomState(0)
+items = (rng.randn(32, 4) * 10 ** rng.uniform(-2, 2, (32, 4))).astype(
+    np.float32)
+w = np.float32(1.7)
+
+def hc():
+    return HealthConfig(target_step_time=1.0, max_threshold=0.8,
+                        min_threshold=0.2, time_between_scaling=1,
+                        window=1, max_instances=2)
+
+def scale_out_at_2(disp, ci, n):
+    if ci == 2:
+        disp.observe_load(2.0)
+
+ref = np.asarray(ElasticDispatcher(devices=jax.devices()[:1],
+                                   dispatch_ahead=0).submit(
+    job, items, replicated=(w,), chunk=4, deliver="host")[0])
+
+ck = os.path.join(workdir, "ck")
+d = ElasticDispatcher(devices=jax.devices(), health_cfg=hc(),
+                      start_members=1, dispatch_ahead=2)
+out, rep = d.submit(job, items, replicated=(w,), chunk=4, deliver="host",
+                    on_chunk=scale_out_at_2,
+                    checkpoint=CheckpointPolicy(path=ck, every_n_chunks=2))
+assert np.asarray(out).tobytes() == ref.tobytes()
+assert rep.scale_events == 1 and rep.members_per_chunk == [1] * 3 + [2] * 5
+assert d.scale_events[-1]["drained_in_flight"] >= 1, d.scale_events
+st = load_journal(ck)
+times = collections.Counter(r["chunk"] for r in st.records
+                            if r["type"] == "chunk")
+assert times == {ci: 1 for ci in range(8)}, times
+assert st.complete is not None and d.in_flight == 0
+
+# the coordinator "died" right before chunk 5's record hit the disk
+cut = os.path.join(workdir, "cut")
+shutil.copytree(ck, cut)
+lines = open(os.path.join(ck, "journal.jsonl")).read().splitlines()
+keep = next(i for i, l in enumerate(lines)
+            if '"type": "chunk"' in l and '"chunk": 5,' in l)
+with open(os.path.join(cut, "journal.jsonl"), "w") as f:
+    f.write("\\n".join(lines[:keep]) + "\\n")
+st = load_journal(cut)
+assert st.complete is None and max(st.chunks) == 4, sorted(st.chunks)
+d2 = ElasticDispatcher(devices=jax.devices(), health_cfg=hc(),
+                       start_members=1, dispatch_ahead=2)
+out2, rep2 = d2.resume(cut, job, items, replicated=(w,), chunk=4)
+assert np.asarray(out2).tobytes() == ref.tobytes()
+assert rep2.chunks_skipped + rep2.chunks_replayed == 8
+assert load_journal(cut).complete is not None
+print("OK")
+""", str(tmp_path)], env=env, capture_output=True, text=True, timeout=600)
+    assert "OK" in r.stdout, r.stdout + r.stderr
+
+
 def test_job_failure_report_persisted_to_journal(tmp_path):
     job, items, w = _job(), _items(), np.float32(2.0)
     ck = str(tmp_path / "fail")
